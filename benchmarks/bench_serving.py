@@ -92,6 +92,7 @@ import jax
 
 from repro.configs.registry import get_arch
 from repro.core.hw import CAMBRICON_LLM_S
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as model_lib
 from repro.serving.engine import Request, ServingEngine
 from repro.serving.router import Router
@@ -870,6 +871,13 @@ def bench_fleet(cfg, params, args) -> list[dict]:
                             temperature=0.8, top_k=20, seed=1000 + r.rid))
                 for r in base]
 
+    if args.transport == "socket" and jax.default_backend() != "cpu":
+        # main() already put params and the reference engine on the chip,
+        # and a chip serves one process at a time
+        raise SystemExit(
+            f"bench_fleet --transport socket: this process holds the "
+            f"{jax.default_backend()} device, so its subprocess workers "
+            f"could not get it; use --transport loopback on a chip host")
     print(f"[fleet] arch={cfg.name} requests={args.requests} "
           f"workers={args.workers} spares={args.spares} "
           f"transport={args.transport}")
@@ -1101,6 +1109,7 @@ def main(argv=None):
         args.page_size = min(args.page_size, 8)
         args.rate = 32.0
 
+    enable_compile_cache()
     cfg = get_arch(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

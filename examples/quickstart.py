@@ -21,6 +21,8 @@ from repro.core.hybrid_gemv import (corrupt_flash_region, hybrid_gemv,
 from repro.sim.llm_perf import decode_token_time
 
 key = jax.random.PRNGKey(0)
+# the Pallas paged kernel compiles for the TPU; on CPU it runs interpreted
+INTERPRET = jax.default_backend() == "cpu"
 
 # -- 1. plan ---------------------------------------------------------------
 h, w = 4096, 4096
@@ -44,10 +46,10 @@ def rel(y):
 
 
 print(f"\nhybrid GeMV rel-error vs float:")
-print(f"  clean weights        : {rel(hybrid_gemv(hw, x)):.4f} (int8 noise)")
-print(f"  BER 2e-4, with ECC   : {rel(hybrid_gemv(noisy, x)):.4f}")
+print(f"  clean weights        : {rel(hybrid_gemv(hw, x, interpret=INTERPRET)):.4f} (int8 noise)")
+print(f"  BER 2e-4, with ECC   : {rel(hybrid_gemv(noisy, x, interpret=INTERPRET)):.4f}")
 print(f"  BER 2e-4, without ECC: "
-      f"{rel(hybrid_gemv(noisy._replace(ecc=None), x)):.4f}")
+      f"{rel(hybrid_gemv(noisy._replace(ecc=None), x, interpret=INTERPRET)):.4f}")
 
 # -- 4. end-to-end estimate --------------------------------------------------
 tt = decode_token_time(ARCHS["llama2-70b"], CAMBRICON_LLM_L, seq_len=1000)

@@ -80,7 +80,7 @@ def corrupt_flash_region(hw: HybridWeights, ber: float, key: jax.Array,
 
 
 def hybrid_gemv(hw: HybridWeights, x: jax.Array,
-                use_kernel: bool = True, interpret: bool = True) -> jax.Array:
+                use_kernel: bool = True, interpret: bool = False) -> jax.Array:
     """y = W x through the two paths; ECC decode precedes the flash path."""
     flash_wq = hw.flash_wq
     if hw.ecc is not None and flash_wq.shape[0]:

@@ -12,8 +12,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import compat
-
 
 def compress(g: jax.Array) -> tuple[jax.Array, jax.Array]:
     absmax = jnp.max(jnp.abs(g))
@@ -37,7 +35,7 @@ def psum_compressed(g: jax.Array, axis: str) -> jax.Array:
     q = jnp.clip(jnp.round(g.astype(jnp.float32) / scale), -127, 127
                  ).astype(jnp.int8)
     summed = jax.lax.psum(q.astype(jnp.int32), axis)
-    return summed.astype(jnp.float32) * scale / compat.axis_size(axis)
+    return summed.astype(jnp.float32) * scale / jax.lax.axis_size(axis)
 
 
 def make_error_feedback_transform():

@@ -16,15 +16,13 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from repro.kernels import compat
-from repro.kernels.compat import shard_map
 
 
 def ring_all_gather(shard: jax.Array, axis: str) -> jax.Array:
     """All-gather along ``axis`` via ppermute ring (overlappable)."""
-    n = compat.axis_size(axis)
+    n = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     perm = [(i, (i + 1) % n) for i in range(n)]
 
@@ -86,7 +84,7 @@ def alpha_split_matmul(x: jax.Array, w: jax.Array, mesh: Mesh,
         if h_act:
             # "read-compute": W sharded on the contraction dim; every shard
             # computes a partial GeMM on resident weights, small output psum'd
-            n = compat.axis_size(axis_store)
+            n = jax.lax.axis_size(axis_store)
             i = jax.lax.axis_index(axis_store)
             x_slice = jax.lax.dynamic_slice_in_dim(
                 x_full, i * (d // n), d // n, axis=1)

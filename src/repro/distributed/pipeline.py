@@ -14,9 +14,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from repro.kernels.compat import shard_map
 
 
 def pipelined_forward(layer_fn, params_stages, x_microbatches, mesh: Mesh,

@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 NEG_INF = -1e30
 
 
@@ -60,7 +58,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
 def gqa_decode_attention(q: jax.Array, k_cache: jax.Array,
                          v_cache: jax.Array, length: jax.Array,
-                         block_k: int = 512, interpret: bool = True
+                         block_k: int = 512, interpret: bool = False
                          ) -> jax.Array:
     """q: [B, H, D]; caches [B, Smax, Hkv, D]; length: scalar or [B] int32.
 
@@ -96,6 +94,6 @@ def gqa_decode_attention(q: jax.Array, k_cache: jax.Array,
             pltpu.VMEM((1, d), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(lens, qr, kr, vr).reshape(b, h, d)

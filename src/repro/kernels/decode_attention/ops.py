@@ -20,7 +20,7 @@ from repro.kernels.decode_attention.decode_attention import gqa_decode_attention
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
 def decode_attention_op(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                         length: jax.Array, block_k: int = 512,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: bool = False) -> jax.Array:
     """q: [B, H, D]; caches [B, Smax, Hkv, D]; length: scalar or [B]."""
     smax = k_cache.shape[1]
     bk = min(block_k, smax)
@@ -36,7 +36,7 @@ def decode_attention_op(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
 def paged_decode_attention_op(q: jax.Array, k_pages: jax.Array,
                               v_pages: jax.Array, block_table: jax.Array,
                               lengths: jax.Array, block_k: int = 512,
-                              interpret: bool = True) -> jax.Array:
+                              interpret: bool = False) -> jax.Array:
     """Paged decode attention over a shared page pool.
 
     q: [B, H, D]; k/v_pages: [P, page, Hkv, D]; block_table: [B, pages_per

@@ -20,61 +20,52 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 
 def _kernel(page_ref, thr_ref, addr_ref, voted_ref, valid_ref, out_ref):
-    page = page_ref[0]                               # [P] uint8 bit patterns
-    vals = page.astype(jnp.int8).astype(jnp.int32)
-    mags = jnp.abs(vals)
-    thr = thr_ref[pl.program_id(0)]  # SMEM ref holds the whole [B] vector
-    # protected-position mask via scatter of valid addrs
-    k = addr_ref.shape[1]
+    b = pl.program_id(0)
+    page = page_ref[0].astype(jnp.int32)             # [R, 128] bit patterns
+    vals = jnp.where(page >= 128, page - 256, page)  # as signed int8 values
+    out = jnp.where(jnp.abs(vals) > thr_ref[b], 0, page)
+    r, c = page.shape
+    pos = (jax.lax.broadcasted_iota(jnp.int32, (r, c), 0) * c
+           + jax.lax.broadcasted_iota(jnp.int32, (r, c), 1))
 
-    out = jnp.where(mags > thr, jnp.uint8(0), page)
-    out_ref[0] = out
+    # a masked select per protected entry: a dynamic single-byte store is
+    # refused by Mosaic (it cannot prove the sublane index a multiple of 8);
+    # later entries win at colliding addresses, as a scatter in order would
+    def body(i, out):
+        hit = (pos == addr_ref[b, i]) & (valid_ref[b, i] != 0)
+        return jnp.where(hit, voted_ref[b, i], out)
 
-    def body(i, _):
-        addr = addr_ref[0, i]
-        val = voted_ref[0, i]
-        ok = valid_ref[0, i]
-        # every index position must be a Slice: a raw int in the tuple breaks
-        # jax 0.4.x's load/store discharge rules (int has no .shape), so the
-        # leading block-row index is pl.ds(0, 1) rather than 0
-        cur = pl.load(out_ref, (pl.ds(0, 1), pl.ds(addr, 1)))
-        new = jnp.where(ok, val, cur[0, 0])
-        pl.store(out_ref, (pl.ds(0, 1), pl.ds(addr, 1)), new[None, None])
-        return 0
-
-    jax.lax.fori_loop(0, k, body, 0)
+    out = jax.lax.fori_loop(0, addr_ref.shape[1], body, out)
+    out_ref[0] = out.astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def ecc_decode_pages(pages: jax.Array, thr: jax.Array, addr: jax.Array,
                      voted: jax.Array, valid: jax.Array,
-                     interpret: bool = True) -> jax.Array:
+                     interpret: bool = False) -> jax.Array:
     """pages: uint8 [B, P]; thr: int32 [B]; addr: int32 [B, K];
     voted: uint8 [B, K]; valid: bool->uint8 [B, K].  Returns corrected pages.
 
     The scatter inside the clamp region restores protected outliers; entries
     with valid=0 keep the clamped value (paper: 2-bit address errors discard
-    the protection).
+    the protection).  P must divide by 128: a page is tiled [P/128, 128].
     """
     b, p = pages.shape
-    k = addr.shape[1]
+    if p % 128:
+        raise ValueError(f"page of {p} bytes does not tile by 128 lanes")
+    tiled = pages.reshape(b, p // 128, 128)
+    block = pl.BlockSpec((1, p // 128, 128), lambda i: (i, 0, 0))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     return pl.pallas_call(
         _kernel,
         grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, p), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, k), lambda i: (i, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, k), lambda i: (i, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, k), lambda i: (i, 0), memory_space=pltpu.SMEM),
-        ],
-        out_specs=pl.BlockSpec((1, p), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, p), jnp.uint8),
+        in_specs=[block, smem, smem, smem, smem],
+        out_specs=block,
+        out_shape=jax.ShapeDtypeStruct(tiled.shape, jnp.uint8),
         interpret=interpret,
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
-    )(pages, thr, addr, voted, valid)
+    )(tiled, thr, addr, voted.astype(jnp.int32),
+      valid.astype(jnp.int32)).reshape(b, p)

@@ -13,7 +13,7 @@ from repro.kernels.ecc_decode.ecc_decode import ecc_decode_pages
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def ecc_decode_op(pages: jax.Array, ecc: ecc_mod.PageECC,
-                  interpret: bool = True) -> jax.Array:
+                  interpret: bool = False) -> jax.Array:
     """pages: uint8 [B, P] + batched PageECC -> corrected uint8 [B, P]."""
     thr = jax.vmap(lambda t: ecc_mod._majority_bits(t, axis=-1))(ecc.threshold)
     addr, valid = jax.vmap(ecc_mod.hamming_correct)(ecc.addr, ecc.addr_parity)

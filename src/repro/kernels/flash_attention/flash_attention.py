@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 NEG_INF = -1e30
 
 
@@ -58,7 +56,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
     "causal", "block_q", "block_k", "interpret"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True, block_q: int = 512,
-                    block_k: int = 512, interpret: bool = True) -> jax.Array:
+                    block_k: int = 512, interpret: bool = False) -> jax.Array:
     """q: [B, H, Sq, D]; k/v: [B, Hkv, Skv, D] -> [B, H, Sq, D].
 
     Sq/Skv must divide by the block sizes (ops.py pads).
@@ -93,6 +91,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(qr, kr, vr).reshape(b, h, sq, d)

@@ -14,7 +14,7 @@ from repro.kernels.flash_attention.flash_attention import flash_attention
                                              "interpret"))
 def flash_attention_op(q: jax.Array, k: jax.Array, v: jax.Array,
                        causal: bool = True, block_q: int = 512,
-                       block_k: int = 512, interpret: bool = True
+                       block_k: int = 512, interpret: bool = False
                        ) -> jax.Array:
     b, h, sq, d = q.shape
     skv = k.shape[2]

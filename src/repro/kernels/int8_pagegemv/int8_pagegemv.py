@@ -17,8 +17,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels import compat
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(w_ref, x_ref, out_ref):
@@ -39,7 +38,7 @@ def _kernel(w_ref, x_ref, out_ref):
 @functools.partial(jax.jit, static_argnames=("tile_h", "tile_w", "interpret"))
 def paged_int8_gemm(w_q: jax.Array, x_q: jax.Array,
                     tile_h: int = 256, tile_w: int = 2048,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool = False) -> jax.Array:
     """int32[h, b] = int8[h, w] @ int8[w, b] with paged VMEM tiling.
 
     Inputs must be pre-padded so tile sizes divide (h, w); see ops.py.
@@ -58,6 +57,6 @@ def paged_int8_gemm(w_q: jax.Array, x_q: jax.Array,
         out_specs=pl.BlockSpec((tile_h, b), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((h, b), jnp.int32),
         interpret=interpret,
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(w_q, x_q)

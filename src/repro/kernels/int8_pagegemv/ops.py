@@ -24,7 +24,7 @@ def _pad_to(a: jax.Array, axis: int, mult: int) -> jax.Array:
                    static_argnames=("tile_h", "tile_w", "interpret"))
 def paged_int8_gemv(w_q: jax.Array, scale: jax.Array, x: jax.Array,
                     tile_h: int = 256, tile_w: int = 2048,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool = False) -> jax.Array:
     """W8A8 GeMV/GeMM through the Pallas kernel.
 
     w_q: int8 [h, w]; scale: f32 [h]; x: float [w] or [w, b] -> f32 [h(, b)].

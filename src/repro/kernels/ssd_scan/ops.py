@@ -12,7 +12,7 @@ from repro.kernels.ssd_scan.ssd_scan import ssd_intra_chunk
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_intra_chunk_op(x: jax.Array, a: jax.Array, b_mat: jax.Array,
                        c_mat: jax.Array, chunk: int = 128,
-                       interpret: bool = True) -> jax.Array:
+                       interpret: bool = False) -> jax.Array:
     """Layout adapter: [B,S,H,P]/[B,S,H]/[B,S,G,N] -> chunked kernel call.
 
     S must divide by ``chunk``. Returns y_diag [B, S, H, P] (f32)."""

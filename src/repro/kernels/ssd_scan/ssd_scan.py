@@ -15,22 +15,23 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels import compat
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
 
 def _kernel(a_ref, b_ref, c_ref, x_ref, o_ref):
-    a = a_ref[0, 0].astype(jnp.float32)       # [Q]
+    a = a_ref[0, 0].astype(jnp.float32)       # [1, Q]
     bmat = b_ref[0, 0].astype(jnp.float32)    # [Q, N]
     cmat = c_ref[0, 0].astype(jnp.float32)    # [Q, N]
     x = x_ref[0, 0].astype(jnp.float32)       # [Q, P]
-    cs = jnp.cumsum(a)
-    q = a.shape[0]
-    diff = cs[:, None] - cs[None, :]
+    q = a.shape[1]
     ii = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
+    # cumsum as a masked row sum: Mosaic has no cumsum lowering
+    cs = jnp.sum(jnp.where(jj <= ii, jnp.broadcast_to(a, (q, q)), 0.0),
+                 axis=1, keepdims=True)               # [Q, 1]
+    diff = cs - jnp.transpose(cs)
     ell = jnp.where(ii >= jj, jnp.exp(diff), 0.0)
     s = jax.lax.dot_general(cmat, bmat, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * ell
@@ -41,11 +42,13 @@ def _kernel(a_ref, b_ref, c_ref, x_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def ssd_intra_chunk(a: jax.Array, b_mat: jax.Array, c_mat: jax.Array,
-                    x: jax.Array, interpret: bool = True) -> jax.Array:
+                    x: jax.Array, interpret: bool = False) -> jax.Array:
     """a: [BH, C, Q]; b_mat/c_mat: [BG, C, Q, N]; x: [BH, C, Q, P].
 
     BH = batch*heads, BG = batch*groups; heads fold onto groups in the
-    BlockSpec index maps. Returns y_diag [BH, C, Q, P] (f32)."""
+    BlockSpec index maps.  ``a`` rides as [BH, C, 1, Q] so its block's last
+    two dims equal the array's, as the TPU lowering requires.  Returns
+    y_diag [BH, C, Q, P] (f32)."""
     bh, nc, qq = a.shape
     bg, n = b_mat.shape[0], b_mat.shape[3]
     p = x.shape[3]
@@ -59,7 +62,7 @@ def ssd_intra_chunk(a: jax.Array, b_mat: jax.Array, c_mat: jax.Array,
         _kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, qq), lambda i, c: (i, c, 0)),
+            pl.BlockSpec((1, 1, 1, qq), lambda i, c: (i, c, 0, 0)),
             pl.BlockSpec((1, 1, qq, n), group_map),
             pl.BlockSpec((1, 1, qq, n), group_map),
             pl.BlockSpec((1, 1, qq, p), lambda i, c: (i, c, 0, 0)),
@@ -67,6 +70,6 @@ def ssd_intra_chunk(a: jax.Array, b_mat: jax.Array, c_mat: jax.Array,
         out_specs=pl.BlockSpec((1, 1, qq, p), lambda i, c: (i, c, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, nc, qq, p), jnp.float32),
         interpret=interpret,
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
-    )(a, b_mat, c_mat, x)
+    )(a.reshape(bh, nc, 1, qq), b_mat, c_mat, x)

@@ -11,7 +11,7 @@ from repro.quant.int4 import GROUP, QuantizedLinear4
 
 
 def w4a16_gemv(q: QuantizedLinear4, x: jax.Array, tile_h: int = 256,
-               tile_w: int = 2048, interpret: bool = True) -> jax.Array:
+               tile_w: int = 2048, interpret: bool = False) -> jax.Array:
     """Not jitted at this level: q.h/q.w are static python ints that drive
     padding/tiling; the inner pallas_call wrapper is jitted."""
     squeeze = x.ndim == 1

@@ -13,36 +13,41 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
 
-
-def _kernel(wp_ref, scale_ref, x_ref, out_ref, *, group):
+def _kernel(wp_ref, scale_ref, xe_ref, xo_ref, out_ref, *, group):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    packed = wp_ref[...]                       # [th, tw//2] uint8
-    lo = (packed & 0xF).astype(jnp.int8) - 8
-    hi = ((packed >> 4) & 0xF).astype(jnp.int8) - 8
-    w_q = jnp.stack([lo, hi], axis=-1).reshape(packed.shape[0], -1)  # [th, tw]
-    th, tw = w_q.shape
+    # byte k of a row packs columns 2k (low nibble) and 2k+1 (high nibble);
+    # rather than interleave the nibbles back (a reshape Mosaic refuses),
+    # each half multiplies the matching even / odd rows of x
+    packed = wp_ref[...].astype(jnp.int32)     # [th, tw//2]
+    lo = ((packed & 0xF) - 8).astype(jnp.float32)
+    hi = (((packed >> 4) & 0xF) - 8).astype(jnp.float32)
+    th, half = lo.shape
     scales = scale_ref[...]                    # [th, tw//group]
-    w = (w_q.reshape(th, tw // group, group).astype(jnp.float32)
-         * scales[:, :, None]).reshape(th, tw)
-    acc = jax.lax.dot_general(
-        w, x_ref[...].astype(jnp.float32), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    out_ref[...] += acc
+    ng = scales.shape[1]
+    # a scale group of `group` columns spans group//2 bytes
+    s = jnp.broadcast_to(scales[:, :, None], (th, ng, group // 2)
+                         ).reshape(th, half)
+    dn = (((1,), (0,)), ((), ()))
+    out_ref[...] += (
+        jax.lax.dot_general(lo * s, xe_ref[...].astype(jnp.float32), dn,
+                            preferred_element_type=jnp.float32)
+        + jax.lax.dot_general(hi * s, xo_ref[...].astype(jnp.float32), dn,
+                              preferred_element_type=jnp.float32))
 
 
 @functools.partial(jax.jit, static_argnames=("tile_h", "tile_w", "group",
                                              "interpret"))
 def w4a16_gemm(w_packed: jax.Array, scales: jax.Array, x: jax.Array,
                tile_h: int = 256, tile_w: int = 2048, group: int = 128,
-               interpret: bool = True) -> jax.Array:
+               interpret: bool = False) -> jax.Array:
     """f32[h, b] = dequant(int4[h, w]) @ f16/f32[w, b].
 
     w_packed: uint8 [h, w//2]; scales: f32 [h, w//group]; x: [w, b].
@@ -58,11 +63,12 @@ def w4a16_gemm(w_packed: jax.Array, scales: jax.Array, x: jax.Array,
         in_specs=[
             pl.BlockSpec((tile_h, tile_w // 2), lambda i, j: (i, j)),
             pl.BlockSpec((tile_h, tile_w // group), lambda i, j: (i, j)),
-            pl.BlockSpec((tile_w, b), lambda i, j: (j, 0)),
+            pl.BlockSpec((tile_w // 2, b), lambda i, j: (j, 0)),
+            pl.BlockSpec((tile_w // 2, b), lambda i, j: (j, 0)),
         ],
         out_specs=pl.BlockSpec((tile_h, b), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((h, b), jnp.float32),
         interpret=interpret,
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-    )(w_packed, scales, x)
+    )(w_packed, scales, x[0::2], x[1::2])

@@ -1,8 +1,12 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+# a virtual-CPU-mesh analysis: never claim an accelerator, which belongs to
+# one process at a time (dryrun_all runs many of these side by side)
+os.environ["JAX_PLATFORMS"] = "cpu"
 
-# NOTE: the two lines above MUST précède every other import (jax locks the
-# device count on first init), which is why __future__ imports are omitted.
+# NOTE: the lines above MUST precede every other import (jax locks the
+# device count and platform on first init), which is why __future__ imports
+# are omitted.
 
 DOC = """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 

@@ -67,8 +67,10 @@ import argparse
 import time
 
 import jax
+import numpy as np
 
 from repro.configs.registry import get_arch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as model_lib
 from repro.quant.convert import quantize_params
 from repro.serving.client import ServingClient
@@ -76,7 +78,7 @@ from repro.serving.router import ROUTE_POLICIES
 from repro.serving.scheduler import POLICIES, SamplingParams, make_scheduler
 
 
-def main():
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-360m")
     ap.add_argument("--reduced", type=int, default=1)
@@ -148,13 +150,34 @@ def main():
                     help="paged KV pool dtype: int8 stores quantized page "
                          "rows + per-row f32 scales (half the spill bytes, "
                          "self-bit-identical across every relocation path)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.quant == "int8":
         args.quant = "w8a8"
+    return args
 
-    cfg = get_arch(args.arch)
-    if args.reduced:
-        cfg = cfg.reduced()
+
+def make_params(cfg, args):
+    """Seeded random weights at ``cfg``'s widths, quantized per ``--quant``."""
+    params = model_lib.init_params(cfg, jax.random.PRNGKey(0),
+                                   max_seq=args.max_seq)
+    if args.quant != "none":
+        params = quantize_params(params, mode=args.quant)
+    return params
+
+
+def make_prompts(n: int, vocab: int, lo: int = 2, hi: int = 9,
+                 seed: int = 42) -> list[list[int]]:
+    """``n`` seeded prompts with lengths in ``[lo, hi)``.  Plain numpy, so a
+    parent that hands the device to socket workers never touches JAX."""
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, vocab, int(rng.integers(lo, hi))).tolist()
+            for _ in range(n)]
+
+
+def build_client(args, cfg, params=None, **engine_kw) -> ServingClient:
+    """The client ``main`` serves through.  Socket fleets rebuild params in
+    their worker processes; otherwise ``params`` defaults to
+    :func:`make_params`.  ``engine_kw`` reaches every ``EngineCore``."""
     if args.workers and args.transport == "socket":
         # subprocess workers rebuild params themselves from (arch, seed)
         # and quantize locally, so nothing heavy ships over the wire;
@@ -169,29 +192,30 @@ def main():
             page_size=args.page_size, eos_id=-1, overlap=args.overlap,
             chunk_prefill=args.chunk_prefill,
             kv_dtype=args.kv_dtype, quant=args.quant)
-        client = ServingClient(router=router, seed_base=args.seed)
-    else:
-        params = model_lib.init_params(cfg, jax.random.PRNGKey(0),
-                                       max_seq=args.max_seq)
-        if args.quant != "none":
-            params = quantize_params(params, mode=args.quant)
-        client = ServingClient(
-            cfg, params, replicas=args.replicas, route=args.route,
-            migrate=not args.no_migrate, seed_base=args.seed,
-            workers=args.workers, transport=args.transport,
-            spares=args.spares,
-            max_batch=args.max_batch, max_seq=args.max_seq, eos_id=-1,
-            mode=args.mode, page_size=args.page_size, overlap=args.overlap,
-            prefix_cache=args.prefix_cache, kv_dtype=args.kv_dtype,
-            scheduler=make_scheduler(args.policy,
-                                     chunk_tokens=args.chunk_prefill
-                                     or None))
-    rng = jax.random.PRNGKey(42)
-    for i in range(args.requests):
-        rng, k = jax.random.split(rng)
-        plen = int(jax.random.randint(k, (), 2, 9))
-        prompt = [int(t) for t in jax.random.randint(
-            k, (plen,), 0, cfg.vocab_size)]
+        return ServingClient(router=router, seed_base=args.seed)
+    if params is None:
+        params = make_params(cfg, args)
+    return ServingClient(
+        cfg, params, replicas=args.replicas, route=args.route,
+        migrate=not args.no_migrate, seed_base=args.seed,
+        workers=args.workers, transport=args.transport,
+        spares=args.spares,
+        max_batch=args.max_batch, max_seq=args.max_seq, eos_id=-1,
+        mode=args.mode, page_size=args.page_size, overlap=args.overlap,
+        prefix_cache=args.prefix_cache, kv_dtype=args.kv_dtype,
+        scheduler=make_scheduler(args.policy,
+                                 chunk_tokens=args.chunk_prefill or None),
+        **engine_kw)
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    enable_compile_cache()
+    cfg = get_arch(args.arch)
+    if args.reduced:
+        cfg = cfg.reduced()
+    client = build_client(args, cfg)
+    for i, prompt in enumerate(make_prompts(args.requests, cfg.vocab_size)):
         client.submit(
             prompt, max_new_tokens=args.max_new,
             priority=i % 3,  # mixed priorities exercise the policy

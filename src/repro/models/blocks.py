@@ -4,9 +4,9 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 
 from repro.configs.base import ModelConfig
-from repro.kernels.compat import shard_map
 from repro.models.attention import (chunked_attention,
                                     decode_attention, gather_paged_rows,
                                     paged_chunk_attention,

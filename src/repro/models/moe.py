@@ -23,11 +23,11 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.distributed import ctx
-from repro.kernels.compat import shard_map
 from repro.models.layers import init_linear, linear
 
 CAPACITY_FACTOR = 2.0

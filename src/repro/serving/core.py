@@ -361,11 +361,11 @@ _jit_sample = jax.jit(sampler.sample_batch)
 
 # the overlapped loop's fused decode+sample step: ONE jitted dispatch per
 # decode step instead of decode followed by a separate sample dispatch.
-# ``greedy_only`` is static (all-greedy batches trace a bare argmax);
-# ``donate`` hands the cache buffers to XLA for in-place reuse — requested
-# only off-CPU (the CPU backend ignores donation with a warning per call)
+# ``greedy_only`` is static (all-greedy batches trace a bare argmax); the
+# cache buffers are donated to XLA for in-place reuse on every backend, so
+# the CPU tests exercise the same buffer lifetimes as the chip
 @functools.lru_cache(maxsize=None)
-def _jit_decode_sample_paged(cfg: ModelConfig, donate: bool):
+def _jit_decode_sample_paged(cfg: ModelConfig):
     def step(p, tok_host, tok_dev, use_dev, c, a, seeds, counts, temps,
              topk, topp, greedy_only):
         return model_lib.decode_and_sample_paged(
@@ -373,12 +373,12 @@ def _jit_decode_sample_paged(cfg: ModelConfig, donate: bool):
             lambda lg: sampler.fused_sample(
                 lg, seeds, counts, temps, topk, topp,
                 greedy_only=greedy_only))
-    kw = {"donate_argnums": (4,)} if donate else {}
-    return jax.jit(step, static_argnames=("greedy_only",), **kw)
+    return jax.jit(step, static_argnames=("greedy_only",),
+                   donate_argnums=(4,))
 
 
 @functools.lru_cache(maxsize=None)
-def _jit_decode_sample(cfg: ModelConfig, donate: bool):
+def _jit_decode_sample(cfg: ModelConfig):
     def step(p, tok_host, tok_dev, use_dev, c, seeds, counts, temps,
              topk, topp, greedy_only):
         return model_lib.decode_and_sample(
@@ -386,8 +386,8 @@ def _jit_decode_sample(cfg: ModelConfig, donate: bool):
             lambda lg: sampler.fused_sample(
                 lg, seeds, counts, temps, topk, topp,
                 greedy_only=greedy_only))
-    kw = {"donate_argnums": (4,)} if donate else {}
-    return jax.jit(step, static_argnames=("greedy_only",), **kw)
+    return jax.jit(step, static_argnames=("greedy_only",),
+                   donate_argnums=(4,))
 
 
 class _LazyPagePayload:
@@ -553,6 +553,9 @@ class EngineCore:
                 "has no page pool to share)")
         self.cfg = cfg
         self.params = params
+        # a replica runs on the device that holds its params (Router.build
+        # spreads replicas over the local devices); its cache lives there too
+        self.device = next(iter(jax.tree.leaves(params)[0].devices()))
         self.max_batch = max_batch
         self.max_seq = max_seq
         self.eos_id = eos_id
@@ -582,9 +585,9 @@ class EngineCore:
             self.pages_per_slot = pages_needed(max_seq, page_size)
             full_pool = max_batch * self.pages_per_slot + 1
             self.num_pages = full_pool if num_pages is None else num_pages
-            self.cache = model_lib.init_paged_cache(
+            self.cache = jax.device_put(model_lib.init_paged_cache(
                 cfg, max_batch, max_seq, page_size=page_size,
-                num_pages=self.num_pages, kv_dtype=kv_dtype)
+                num_pages=self.num_pages, kv_dtype=kv_dtype), self.device)
             self.kv_page_bytes = model_lib.kv_page_bytes(
                 cfg, page_size, model_lib.paged_pool_dtype(self.cache))
             # hybrid: per-slot Mamba state checkpoints, filled on suspend
@@ -625,16 +628,16 @@ class EngineCore:
             self._prefill_chunk = (_jit_prefill_chunk(cfg)
                                    if self._chunk_ok else None)
         else:
-            self.cache = model_lib.init_cache(cfg, max_batch, max_seq)
+            self.cache = jax.device_put(
+                model_lib.init_cache(cfg, max_batch, max_seq), self.device)
             self.last_token = jnp.zeros((max_batch,), jnp.int32)
             self._wave_last_np = np.zeros((max_batch,), np.int32)
             self._wave_len = 0  # host prediction of cache["len"]
             self._decode = _jit_decode(cfg)
         if overlap:
-            donate = jax.default_backend() != "cpu"
             self._decode_sample = (
-                _jit_decode_sample_paged(cfg, donate)
-                if mode == "continuous" else _jit_decode_sample(cfg, donate))
+                _jit_decode_sample_paged(cfg)
+                if mode == "continuous" else _jit_decode_sample(cfg))
         self._san = _sanitize.load()  # None unless REPRO_SANITIZE=1
 
     # ------------------------------------------------------------------
@@ -1059,7 +1062,8 @@ class EngineCore:
         # swap_in scatter then composes with it by dataflow instead of the
         # jit call blocking on an implicit synchronous upload
         self.cache = _jit_swap_in(self.cache, bpids,
-                                  *(jax.device_put(a) for a in comps))
+                                  *(jax.device_put(a, self.device)
+                                    for a in comps))
 
     def _spill(self, items: list[tuple[tuple[int, int], int]]) -> int:
         """Swap ``(key=(slot, page_idx), pid)`` hot pages out to flash;

@@ -236,7 +236,9 @@ def spawn_worker(arch: str, *, reduced: bool = True, max_batch: int = 4,
     The worker rebuilds its params deterministically from
     ``(arch, reduced, seed, max_seq)`` — ``init_params`` is deterministic
     on a fixed backend, so nothing heavy ships over the wire and every
-    worker of a fleet holds bit-identical weights.
+    worker of a fleet holds bit-identical weights.  A worker that cannot
+    get a device exits within its ``DEVICE_TIMEOUT_S``, and its output
+    ends up in the ``TransportError`` raised here.
     """
     import repro
     # repro is a namespace package (no __init__.py): locate src/ via
@@ -264,7 +266,7 @@ def spawn_worker(arch: str, *, reduced: bool = True, max_batch: int = 4,
         if proc.poll() is not None:
             raise TransportError(
                 f"worker exited with {proc.returncode} before ready:\n"
-                + "".join(lines[-20:]))
+                + "".join(lines[-40:]))
         remaining = deadline - time.monotonic()
         if remaining <= 0:
             proc.kill()

@@ -40,6 +40,7 @@ import argparse
 import dataclasses
 import socket
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.core import EngineCore
 from repro.serving.fleet import wire
 
@@ -180,6 +181,34 @@ def build_core(arch: str, *, reduced: bool = True, max_batch: int = 4,
         scheduler=make_scheduler(policy, chunk_tokens=chunk_prefill or None))
 
 
+# how long a worker waits for its device before it gives up: a chip serves
+# one process at a time, and a worker whose chip is held elsewhere must say
+# so while the parent still waits for READY, not hang until its timeout
+DEVICE_TIMEOUT_S = 60.0
+
+
+def acquire_device(timeout_s: float = DEVICE_TIMEOUT_S):
+    """Initialise JAX's default backend, or exit within ``timeout_s``.
+
+    A backend that refuses (the chip is held by another process) raises
+    here; one that blocks is cut off by faulthandler's watchdog thread,
+    which dumps the stacks and exits even while the main thread sits in
+    native code."""
+    import faulthandler
+
+    import jax
+
+    print(f"FLEET-WORKER acquiring a device (gives up after {timeout_s:.0f}s)",
+          flush=True)
+    faulthandler.dump_traceback_later(timeout_s, exit=True)
+    try:
+        dev = jax.devices()[0]
+    finally:
+        faulthandler.cancel_dump_traceback_later()
+    print(f"FLEET-WORKER device {dev.platform} {dev.device_kind}", flush=True)
+    return dev
+
+
 def main(argv=None) -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-360m")
@@ -203,6 +232,8 @@ def main(argv=None) -> None:
                     help="param init seed — must match the fleet's")
     ap.add_argument("--name", default="worker")
     args = ap.parse_args(argv)
+    enable_compile_cache()
+    acquire_device()
     core = build_core(
         args.arch, reduced=bool(args.reduced), max_batch=args.max_batch,
         max_seq=args.max_seq, page_size=args.page_size, eos_id=args.eos_id,

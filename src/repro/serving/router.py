@@ -47,6 +47,8 @@ import copy
 import zlib
 from typing import Iterable, Optional
 
+import jax
+
 from repro.serving.core import EngineCore, EngineStats, Request, \
     RequestOutput
 
@@ -101,13 +103,19 @@ class Router:
     def build(cls, cfg, params, replicas: int = 1,
               policy: str = "round_robin", migrate: bool = True,
               **engine_kw) -> "Router":
-        """N identical replicas over one (cfg, params) pair.  The jitted
-        step functions are shared per-config, so extra replicas cost slot
-        bookkeeping and KV pool memory, not compilations."""
+        """N identical replicas over one (cfg, params) pair.  Replica
+        ``k`` runs on local device ``k % n_devices``: its params are copied
+        there and its KV cache follows them.  The jitted step functions are
+        shared per-config, so extra replicas on one device cost slot
+        bookkeeping and KV pool memory, not compilations; each further
+        device compiles the steps once for itself."""
         if replicas < 1:
             raise ValueError(f"replicas must be >= 1, got {replicas}")
+        # one params copy per device, shared by the replicas placed there
+        placed = [jax.device_put(params, d)
+                  for d in jax.local_devices()[:replicas]]
         cores = []
-        for _ in range(replicas):
+        for k in range(replicas):
             kw = dict(engine_kw)
             # stateful schedulers (DRR's deficit ring, EDF/priority are
             # stateless but uniform treatment is free) must not be shared:
@@ -115,7 +123,7 @@ class Router:
             # corrupt their per-queue bookkeeping
             if kw.get("scheduler") is not None:
                 kw["scheduler"] = copy.deepcopy(kw["scheduler"])
-            cores.append(EngineCore(cfg, params, **kw))
+            cores.append(EngineCore(cfg, placed[k % len(placed)], **kw))
         return cls(cores, policy=policy, migrate=migrate)
 
     # ------------------------------------------------------------------

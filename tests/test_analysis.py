@@ -58,26 +58,6 @@ def test_async_aliasing_accepts_copied_buffer(tmp_path):
     assert _lint(tmp_path, good, "async-aliasing") == []
 
 
-def test_pallas_raw_index_flags_raw_int(tmp_path):
-    # the ecc_decode bug: raw 0 in the pl.store index tuple
-    bad = """
-        def kernel(out_ref, addr):
-            pl.store(out_ref, (0, pl.ds(addr, 1)), val)
-    """
-    found = _lint(tmp_path, bad, "pallas-raw-index")
-    assert len(found) == 1 and "int constant" in found[0].message
-
-
-def test_pallas_raw_index_accepts_ds_everywhere(tmp_path):
-    good = """
-        def kernel(out_ref, addr):
-            pl.store(out_ref, (pl.ds(0, 1), pl.ds(addr, 1)), val)
-            x = q_ref[0]          # raw ref subscripts are fine
-            y = pickle.load(f)    # non-pallas load untouched
-    """
-    assert _lint(tmp_path, good, "pallas-raw-index") == []
-
-
 def test_boolean_select_trap_flags_numeric_and_sentinel(tmp_path):
     bad = """
         _NO_BUDGET = 1 << 30

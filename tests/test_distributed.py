@@ -24,6 +24,7 @@ def _check(r):
 def test_moe_expert_parallel_equivalence():
     _check(_run("""
 import jax, jax.numpy as jnp
+from jax.sharding import AxisType
 from repro.configs.registry import ASSIGNED_ARCHS
 from repro.distributed import ctx
 from repro.models import moe as moe_mod
@@ -32,7 +33,7 @@ key = jax.random.PRNGKey(0)
 p = moe_mod.init_moe(key, cfg, jnp.float32)
 x = jax.random.normal(jax.random.fold_in(key,1), (4, 8, cfg.d_model), jnp.float32)
 y_local = moe_mod.moe_ffn(p, x, cfg)
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = jax.make_mesh((2, 4), ('data', 'model'), axis_types=(AxisType.Auto,) * 2)
 with ctx.lowering_ctx(mesh=mesh):
     with mesh:
         y_s = jax.jit(lambda p, x: moe_mod.moe_ffn(p, x, cfg))(p, x)
@@ -44,8 +45,9 @@ assert rel < 2e-2, rel
 def test_hybrid_stream_primitives():
     _check(_run("""
 import jax, jax.numpy as jnp
+from jax.sharding import AxisType
 from repro.distributed.hybrid_stream import streamed_matmul_chain, alpha_split_matmul
-mesh = jax.make_mesh((8,), ('data',))
+mesh = jax.make_mesh((8,), ('data',), axis_types=(AxisType.Auto,) * 1)
 key = jax.random.PRNGKey(0)
 x = jax.random.normal(key, (4, 64))
 ws = [jax.random.normal(jax.random.fold_in(key, i), (64, 64)) * 0.1
@@ -66,8 +68,9 @@ with mesh:
 def test_pipeline_parallel_correctness():
     _check(_run("""
 import jax, jax.numpy as jnp
+from jax.sharding import AxisType
 from repro.distributed.pipeline import pipelined_forward
-mesh = jax.make_mesh((4,), ('pod',))
+mesh = jax.make_mesh((4,), ('pod',), axis_types=(AxisType.Auto,) * 1)
 key = jax.random.PRNGKey(0)
 n_stages, m, mb, d = 4, 6, 2, 16
 ws = jax.random.normal(key, (n_stages, d, d)) * 0.3
@@ -104,10 +107,11 @@ for n in (8, 6, 4):
 def test_sharding_rules_cover_all_archs():
     _check(_run("""
 import jax, jax.numpy as jnp
+from jax.sharding import AxisType
 from repro.configs.registry import ASSIGNED_ARCHS
 from repro.distributed import sharding as shd
 from repro.launch import specs as specs_lib
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = jax.make_mesh((2, 4), ('data', 'model'), axis_types=(AxisType.Auto,) * 2)
 for name, cfg in ASSIGNED_ARCHS.items():
     ps = specs_lib.param_specs(cfg.reduced(), max_seq=64, quant=False)
     tree = shd.params_shardings(ps, mesh)  # must not raise
@@ -120,10 +124,10 @@ print('ok')
 def test_grad_compress_allreduce_traffic():
     _check(_run("""
 import jax, jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax import shard_map
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.distributed.grad_compress import psum_compressed
-from repro.kernels.compat import shard_map
-mesh = jax.make_mesh((8,), ('data',))
+mesh = jax.make_mesh((8,), ('data',), axis_types=(AxisType.Auto,) * 1)
 g = jax.random.normal(jax.random.PRNGKey(0), (8, 128)) * 0.1
 with mesh:
     out = shard_map(lambda g: psum_compressed(g, 'data'), mesh=mesh,
@@ -133,3 +137,34 @@ ref = g.mean(0)
 rel = float(jnp.linalg.norm(out[0] - ref) / jnp.linalg.norm(ref))
 assert rel < 0.05, rel
 """))
+
+
+def test_router_replicas_each_on_their_own_device():
+    # Router.build puts replica i's params and paged KV on local device i;
+    # four replicas serve the same greedy streams as one replica
+    _check(_run("""
+import jax
+from repro.configs.registry import get_arch
+from repro.models import model as M
+from repro.serving.client import ServingClient
+from repro.serving.scheduler import SamplingParams
+cfg = get_arch('smollm-360m').reduced()
+params = M.init_params(cfg, jax.random.PRNGKey(0), max_seq=64)
+prompts = [[3 + i, 5, 7 + 2 * i, 11][:2 + i % 3] for i in range(12)]
+streams = {}
+for n in (4, 1):
+    client = ServingClient(cfg, params, replicas=n, route='least_loaded',
+                           max_batch=2, max_seq=64, page_size=8, eos_id=-1)
+    for i, core in enumerate(client.router.cores):
+        held = {d for leaf in jax.tree.leaves(core.cache)
+                for d in leaf.devices()}
+        assert held == {jax.devices()[i]}, (i, held)
+    hs = [client.submit(p, max_new_tokens=6,
+                        sampling=SamplingParams(temperature=0.0))
+          for p in prompts]
+    client.run()
+    streams[n] = [h.request.out_tokens for h in hs]
+    assert all(len(s) == 6 for s in streams[n]), streams[n]
+assert streams[4] == streams[1]
+print('ok')
+""", devices=4))

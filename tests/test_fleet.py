@@ -450,6 +450,43 @@ def test_socket_sigkill_mid_decode_bit_identical(arch):
         fl.close()
 
 
+def test_socket_worker_without_a_device_fails_fast(monkeypatch):
+    """A worker whose backend cannot start exits at once, and its error is
+    in the READY-wait failure, not a startup timeout later."""
+    import time
+
+    from repro.serving.fleet.transport import TransportError, spawn_worker
+
+    monkeypatch.setenv("JAX_PLATFORMS", "cuda")  # no such backend here
+    t0 = time.monotonic()
+    with pytest.raises(TransportError) as e:
+        spawn_worker("smollm-360m", startup_timeout_s=300.0)
+    assert time.monotonic() - t0 < 60.0
+    assert "exited with 1 before ready" in str(e.value)
+    assert "acquiring a device" in str(e.value)
+
+
+def test_socket_serve_parent_never_initialises_jax():
+    """``serve --transport socket`` leaves the device to its workers: the
+    parent builds prompts with numpy and never starts a JAX backend."""
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    r = subprocess.run([sys.executable, "-c", """
+from repro.launch import serve
+serve.main(['--requests', '2', '--max-new', '4', '--workers', '1',
+            '--transport', 'socket', '--max-seq', '64'])
+from jax._src import xla_bridge
+assert not xla_bridge._backends, sorted(xla_bridge._backends)
+print('parent clean')
+"""], env=dict(os.environ, PYTHONPATH=os.path.join(repo, "src")),
+        capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0, r.stdout + r.stderr
+    assert "requests=2 tokens_out=8" in r.stdout
+    assert "parent clean" in r.stdout
+
+
 def test_socket_transport_survives_split_frames(smollm):
     """Socket-level framing adversity: a reply split across many tiny
     TCP segments reassembles; the decoder never delivers a torn frame."""

@@ -34,7 +34,7 @@ def test_int8_pagegemv(h, w, b, dtype):
     W = (jax.random.normal(k1, (h, w)) * 0.1).astype(dtype)
     x = jax.random.normal(k2, (w, b) if b > 1 else (w,)).astype(dtype)
     q = quantize_weight(W.astype(jnp.float32))
-    y_k = paged_int8_gemv(q.w_q, q.scale, x)
+    y_k = paged_int8_gemv(q.w_q, q.scale, x, interpret=True)
     y_r = paged_int8_gemv_ref(q.w_q, q.scale, x)
     np.testing.assert_array_equal(np.asarray(y_k), np.asarray(y_r))
 
@@ -56,7 +56,8 @@ def test_flash_attention(b, h, hkv, s, d, causal):
     q = jax.random.normal(k1, (b, h, s, d), jnp.float32)
     k = jax.random.normal(k2, (b, hkv, s, d), jnp.float32)
     v = jax.random.normal(k3, (b, hkv, s, d), jnp.float32)
-    out = flash_attention_op(q, k, v, causal=causal, block_q=128, block_k=128)
+    out = flash_attention_op(q, k, v, causal=causal, block_q=128, block_k=128,
+                             interpret=True)
     ref = attention_ref(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
@@ -71,7 +72,8 @@ def test_flash_attention_bf16():
     q = jax.random.normal(k1, (1, 4, 256, 64), jnp.bfloat16)
     k = jax.random.normal(k2, (1, 4, 256, 64), jnp.bfloat16)
     v = jax.random.normal(k3, (1, 4, 256, 64), jnp.bfloat16)
-    out = flash_attention_op(q, k, v, block_q=128, block_k=128)
+    out = flash_attention_op(q, k, v, block_q=128, block_k=128,
+                             interpret=True)
     ref = attention_ref(q, k, v)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
@@ -94,7 +96,8 @@ def test_decode_attention(b, h, hkv, smax, d, length):
     q = jax.random.normal(k1, (b, h, d), jnp.float32)
     kc = jax.random.normal(k2, (b, smax, hkv, d), jnp.float32)
     vc = jax.random.normal(k3, (b, smax, hkv, d), jnp.float32)
-    out = decode_attention_op(q, kc, vc, jnp.int32(length), block_k=128)
+    out = decode_attention_op(q, kc, vc, jnp.int32(length), block_k=128,
+                              interpret=True)
     ref = ref_fn(q, kc, vc, jnp.int32(length))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
@@ -117,7 +120,7 @@ def test_decode_attention_lengths_vector(b, h, hkv, smax, d):
     vc = jax.random.normal(k3, (b, smax, hkv, d), jnp.float32)
     lens = jax.random.randint(k4, (b,), 1, smax + 1).astype(jnp.int32)
     lens = lens.at[0].set(0)  # inactive slot lane
-    out = decode_attention_op(q, kc, vc, lens, block_k=128)
+    out = decode_attention_op(q, kc, vc, lens, block_k=128, interpret=True)
     ref = decode_attention_ref(q, kc, vc, lens)
     np.testing.assert_allclose(np.asarray(out[1:]), np.asarray(ref[1:]),
                                rtol=2e-5, atol=2e-5)
@@ -141,7 +144,7 @@ def test_paged_decode_attention_matches_dense():
     q = jax.random.normal(k3, (b, h, d), jnp.float32)
     lens = jnp.asarray([page * pps, 7, 23], jnp.int32)
     out = paged_decode_attention_op(q, k_pages, v_pages, block, lens,
-                                    block_k=32)
+                                    block_k=32, interpret=True)
     k_dense = k_pages[block].reshape(b, pps * page, hkv, d)
     v_dense = v_pages[block].reshape(b, pps * page, hkv, d)
     ref = decode_attention_ref(q, k_dense, v_dense, lens)
@@ -162,7 +165,7 @@ def test_w4a16_gemv(h, w, b):
     W = jax.random.normal(k1, (h, w)) * 0.1
     x = jax.random.normal(k2, (w, b) if b > 1 else (w,))
     q = quantize_weight4(W)
-    y_k = w4a16_gemv(q, x)
+    y_k = w4a16_gemv(q, x, interpret=True)
     y_r = w4a16_gemv_ref(q, x)
     np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_r),
                                rtol=2e-5, atol=2e-5)
@@ -190,7 +193,7 @@ def test_ecc_decode_kernel(ber):
         e = ecc.inject_ecc_bitflips(e, ber, jax.random.fold_in(key, 10))
     else:
         pages_n = pages
-    out_k = np.asarray(ecc_decode_op(pages_n, e))
+    out_k = np.asarray(ecc_decode_op(pages_n, e, interpret=True))
     out_r = np.asarray(ecc.decode_pages(pages_n, e))
     # Corrupted addresses may collide post-Hamming-correction; write order at
     # collisions is implementation-defined, so exclude colliding positions.
@@ -221,7 +224,7 @@ def test_ssd_intra_chunk(b, s, h, g, p, n, chunk):
     a = -jnp.abs(jax.random.normal(keys[1], (b, s, h))) * 0.1
     bm = jax.random.normal(keys[2], (b, s, g, n), jnp.float32) * 0.3
     cm = jax.random.normal(keys[3], (b, s, g, n), jnp.float32) * 0.3
-    y_k = ssd_intra_chunk_op(x, a, bm, cm, chunk=chunk)
+    y_k = ssd_intra_chunk_op(x, a, bm, cm, chunk=chunk, interpret=True)
     nc = s // chunk
     ar = a.reshape(b, nc, chunk, h).transpose(0, 3, 1, 2).reshape(b * h, nc, chunk)
     br = bm.reshape(b, nc, chunk, g, n).transpose(0, 3, 1, 2, 4).reshape(b * g, nc, chunk, n)
@@ -246,7 +249,8 @@ def test_ssd_kernel_matches_model_diag_plus_offdiag():
     bm = jax.random.normal(keys[2], (b, s, g, n), jnp.float32) * 0.3
     cm = jax.random.normal(keys[3], (b, s, g, n), jnp.float32) * 0.3
     y_full, _ = ssd_chunked(x, a, bm, cm, chunk=chunk)
-    y_diag = ssd_intra_chunk_op(x, a, bm, cm, chunk=chunk)
+    y_diag = ssd_intra_chunk_op(x, a, bm, cm, chunk=chunk,
+                                interpret=True)
     # single chunk -> no inter-chunk term: y_diag must equal the full output
     np.testing.assert_allclose(np.asarray(y_diag), np.asarray(y_full, np.float32),
                                rtol=1e-4, atol=1e-4)

@@ -121,7 +121,7 @@ def test_paged_int8_gemv_outlier_matches_ref_and_is_accurate():
     x = jax.random.normal(k2, (w, b), jnp.float32)
     x = x.at[:, 1].multiply(100.0)
     q = quantize_weight(W)
-    y_k = paged_int8_gemv(q.w_q, q.scale, x)
+    y_k = paged_int8_gemv(q.w_q, q.scale, x, interpret=True)
     y_r = paged_int8_gemv_ref(q.w_q, q.scale, x)
     np.testing.assert_array_equal(np.asarray(y_k), np.asarray(y_r))
     y_ref = dequantize(q.w_q, q.scale) @ x
@@ -145,7 +145,7 @@ def test_w4a16_gemv_tile_clamp_width_sweep(w):
     W = jax.random.normal(k1, (h, w), jnp.float32) * 0.1
     x = jax.random.normal(k2, (w, 3), jnp.float32)
     q = quantize_weight4(W)
-    y_k = w4a16_gemv(q, x)
+    y_k = w4a16_gemv(q, x, interpret=True)
     y_r = w4a16_gemv_ref(q, x)
     np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_r),
                                rtol=2e-5, atol=2e-5)
@@ -170,7 +170,7 @@ def test_w4a16_tile_width_no_inflation_at_group():
     x = jnp.ones((GROUP,), jnp.float32)
     w4ops.w4a16_gemm, _ = spy, None
     try:
-        w4ops.w4a16_gemv(q, x)
+        w4ops.w4a16_gemv(q, x, interpret=True)
     finally:
         w4ops.w4a16_gemm = orig
     assert seen["tile_w"] == GROUP

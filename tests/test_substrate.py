@@ -71,7 +71,7 @@ def test_hybrid_gemv_paths_match():
     w = jax.random.normal(KEY, (512, 2048)) * 0.05
     x = jax.random.normal(jax.random.fold_in(KEY, 1), (2048,))
     hw = plan_and_quantize(w, CAMBRICON_LLM_S)
-    y_kernel = hybrid_gemv(hw, x, use_kernel=True)
+    y_kernel = hybrid_gemv(hw, x, use_kernel=True, interpret=True)
     y_ref = hybrid_gemv(hw, x, use_kernel=False)
     np.testing.assert_array_equal(np.asarray(y_kernel), np.asarray(y_ref))
     rel = float(jnp.linalg.norm(y_ref - w @ x) / jnp.linalg.norm(w @ x))
@@ -88,9 +88,10 @@ def test_hybrid_gemv_ecc_recovers():
     ref = w @ x
     hw = plan_and_quantize(w, CAMBRICON_LLM_S, with_ecc=True)
     noisy = corrupt_flash_region(hw, 2e-4, jax.random.fold_in(KEY, 3))
-    err_ecc = float(jnp.linalg.norm(hybrid_gemv(noisy, x) - ref))
+    err_ecc = float(jnp.linalg.norm(
+        hybrid_gemv(noisy, x, interpret=True) - ref))
     err_raw = float(jnp.linalg.norm(
-        hybrid_gemv(noisy._replace(ecc=None), x) - ref))
+        hybrid_gemv(noisy._replace(ecc=None), x, interpret=True) - ref))
     assert err_ecc < err_raw
 
 

@@ -102,60 +102,7 @@ class AsyncAliasingRule(Rule):
 
 
 # ----------------------------------------------------------------------
-# 2. pallas-raw-index — the ecc_decode raw-int load/store bug
-# ----------------------------------------------------------------------
-@register
-class PallasRawIndexRule(Rule):
-    """A raw (non-``pl.ds``) element in a ``pl.load`` / ``pl.store`` index
-    tuple.
-
-    Historical bug: the ``ecc_decode`` kernel indexed its output ref with
-    a raw int in the ``pl.store`` index tuple; jax 0.4.x's load/store
-    discharge rules require every index position to be a Slice (an int has
-    no ``.shape``), so the kernel failed — the 7th red tier-1 test of
-    PR 6.  The leading block-row index must be ``pl.ds(0, 1)``, not ``0``.
-    """
-
-    name = "pallas-raw-index"
-    description = "raw int in a pl.load/pl.store index tuple (need pl.ds)"
-    hint = ("wrap every index-tuple element in pl.ds(start, size) — "
-            "pl.load/pl.store require Slice at each position; raw ints "
-            "break the jax 0.4.x discharge rules")
-
-    _DS_NAMES = {"ds", "dslice", "Slice"}
-
-    def check(self, src: SourceFile) -> Iterator[Finding]:
-        for node in ast.walk(src.tree):
-            if not isinstance(node, ast.Call):
-                continue
-            if _terminal_name(node.func) not in ("load", "store"):
-                continue
-            # only pl./pallas. load/store (not pickle.load & friends)
-            if not isinstance(node.func, ast.Attribute):
-                continue
-            base = _dotted(node.func)
-            if not re.match(r"(pl|pallas|plgpu|pltpu)\.", base):
-                continue
-            if len(node.args) < 2:
-                continue
-            idx = node.args[1]
-            if not isinstance(idx, ast.Tuple):
-                continue  # single index / precomputed tuple: not analyzable
-            for elt in idx.elts:
-                if (isinstance(elt, ast.Call)
-                        and _terminal_name(elt.func) in self._DS_NAMES):
-                    continue
-                desc = ("int constant" if isinstance(elt, ast.Constant)
-                        else type(elt).__name__)
-                yield Finding(
-                    src.path, elt.lineno, self.name,
-                    f"{base}(...) index tuple has a raw {desc} element "
-                    f"where a pl.ds slice is required",
-                    self.hint)
-
-
-# ----------------------------------------------------------------------
-# 3. boolean-select-trap — the plan_remesh / w4a16 / arrival_s class
+# 2. boolean-select-trap — the plan_remesh / w4a16 / arrival_s class
 # ----------------------------------------------------------------------
 @register
 class BooleanSelectTrapRule(Rule):
@@ -244,7 +191,7 @@ class BooleanSelectTrapRule(Rule):
 
 
 # ----------------------------------------------------------------------
-# 4. donation-use-after — reading a buffer after donating it
+# 3. donation-use-after — reading a buffer after donating it
 # ----------------------------------------------------------------------
 @register
 class DonationUseAfterRule(Rule):
@@ -252,7 +199,7 @@ class DonationUseAfterRule(Rule):
     position of a jitted call in the same scope.
 
     Hazard class behind the overlapped loop's donated cache buffers
-    (``_jit_decode_sample*`` donate the cache off-CPU): XLA reuses a
+    (``_jit_decode_sample*`` donate the cache): XLA reuses a
     donated buffer in place, so any later host read of the old reference
     sees freed/overwritten memory.  Best-effort static check: it follows
     ``name = jax.jit(fn, donate_argnums=...)`` bindings and flags later
@@ -349,7 +296,7 @@ class DonationUseAfterRule(Rule):
 
 
 # ----------------------------------------------------------------------
-# 5. wire-field-drift — serving dataclasses vs the fleet codec manifest
+# 4. wire-field-drift — serving dataclasses vs the fleet codec manifest
 # ----------------------------------------------------------------------
 @register
 class WireFieldDriftRule(Rule):
@@ -460,7 +407,7 @@ class WireFieldDriftRule(Rule):
 
 
 # ----------------------------------------------------------------------
-# 6. nondeterminism — unseeded randomness / wall-clock in hot paths
+# 5. nondeterminism — unseeded randomness / wall-clock in hot paths
 # ----------------------------------------------------------------------
 @register
 class NondeterminismRule(Rule):
@@ -520,7 +467,7 @@ class NondeterminismRule(Rule):
 
 
 # ----------------------------------------------------------------------
-# 7. jit-in-loop — retrace explosion
+# 6. jit-in-loop — retrace explosion
 # ----------------------------------------------------------------------
 @register
 class JitInLoopRule(Rule):
@@ -566,7 +513,7 @@ class JitInLoopRule(Rule):
 
 
 # ----------------------------------------------------------------------
-# 8. mutable-default — shared mutable default arguments
+# 7. mutable-default — shared mutable default arguments
 # ----------------------------------------------------------------------
 @register
 class MutableDefaultRule(Rule):
