@@ -174,7 +174,6 @@ def bench_mode(mode: str, cfg, params, args, timed_seed: int) -> dict:
         "tokens": s.tokens_out,
         "tok_per_s": s.tokens_out / wall,
         "tok_per_step": s.tokens_out / max(s.decode_steps, 1),
-        "tok_per_decode_s": s.tokens_out / max(s.wall_decode_s, 1e-9),
         "prefills": s.prefills,
         "admission_p50": s.percentiles("admission_wait_s")["p50"],
         "admission_p99": s.percentiles("admission_wait_s")["p99"],
@@ -191,12 +190,12 @@ def bench_admission(cfg, params, args) -> list[dict]:
 
     rows = [bench_mode(m, cfg, params, args, timed_seed=args.seed)
             for m in ("wave", "continuous")]
-    hdr = ("mode", "wall_s", "tok/s", "tok/step", "tok/dec_s", "prefills",
+    hdr = ("mode", "wall_s", "tok/s", "tok/step", "prefills",
            "adm_p50", "adm_p99", "lat_p50", "lat_p99")
     print(" ".join(f"{h:>10}" for h in hdr))
     for r in rows:
         print(f"{r['mode']:>10} {r['wall_s']:>10.2f} {r['tok_per_s']:>10.1f} "
-              f"{r['tok_per_step']:>10.2f} {r['tok_per_decode_s']:>10.1f} "
+              f"{r['tok_per_step']:>10.2f} "
               f"{r['prefills']:>10d} "
               f"{r['admission_p50']:>10.3f} {r['admission_p99']:>10.3f} "
               f"{r['latency_p50']:>10.3f} {r['latency_p99']:>10.3f}")

@@ -155,7 +155,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models import model as model_lib
-from repro.serving import sampler
+from repro.serving import sampler, spans
 from repro import _sanitize
 from repro.serving.kv_cache import (OutOfPages, PageAllocator, PrefixIndex,
                                     ResumeEntry, TieredPageAllocator,
@@ -200,6 +200,8 @@ class Request:
     t_admit: float = 0.0
     t_first_token: float = 0.0
     t_done: float = 0.0
+    # when the first token's event left the engine (``drain_outputs``)
+    t_first_out: float = 0.0
 
     def __post_init__(self):
         if self.sampling is None:
@@ -316,22 +318,30 @@ def _batch_extras(cfg: ModelConfig, batch: int) -> dict:
 
 # jitted step functions are shared per-config (ModelConfig is frozen and
 # hashable) so every replica of a router — and rebuilt engines, e.g. the
-# wave-vs-continuous benchmark — reuses compile caches instead of retracing
+# wave-vs-continuous benchmark — reuses compile caches instead of retracing.
+# Each wraps a NAMED function: its XLA module is ``jit_<name>``, the name the
+# device trace attributes device time by, whatever the code around it is
+# called (tests/test_tracing.py pins every name)
 @functools.lru_cache(maxsize=None)
 def _jit_decode(cfg: ModelConfig):
-    return jax.jit(lambda p, t, c: model_lib.decode_step(p, cfg, t, c))
+    def decode(p, t, c):
+        return model_lib.decode_step(p, cfg, t, c)
+    return jax.jit(decode)
 
 
 @functools.lru_cache(maxsize=None)
 def _jit_decode_paged(cfg: ModelConfig):
-    return jax.jit(
-        lambda p, t, c, a: model_lib.decode_step_paged(p, cfg, t, c, a))
+    def decode_paged(p, t, c, a):
+        return model_lib.decode_step_paged(p, cfg, t, c, a)
+    return jax.jit(decode_paged)
 
 
 @functools.lru_cache(maxsize=None)
 def _jit_prefill_slots(cfg: ModelConfig):
-    return jax.jit(lambda p, toks, tls, c, ss: model_lib.prefill_into_slots(
-        p, cfg, toks, tls, c, ss, _batch_extras(cfg, toks.shape[0])))
+    def prefill_slots(p, toks, tls, c, ss):
+        return model_lib.prefill_into_slots(
+            p, cfg, toks, tls, c, ss, _batch_extras(cfg, toks.shape[0]))
+    return jax.jit(prefill_slots)
 
 
 @functools.lru_cache(maxsize=None)
@@ -339,24 +349,29 @@ def _jit_prefill_chunk(cfg: ModelConfig):
     # one trace per chunk-length bucket (power-of-two, floor = page size):
     # start/chunk_len/slot are traced scalars, so the trace count stays
     # O(log max_seq) while per-chunk compute scales with the budget
-    return jax.jit(
-        lambda p, toks, start, clen, c, slot:
-        model_lib.prefill_chunk_into_slot(p, cfg, toks, start, clen, c,
-                                          slot))
+    def prefill_chunk(p, toks, start, clen, c, slot):
+        return model_lib.prefill_chunk_into_slot(p, cfg, toks, start, clen,
+                                                 c, slot)
+    return jax.jit(prefill_chunk)
 
 
 @functools.lru_cache(maxsize=None)
 def _jit_prefill(cfg: ModelConfig):
-    return jax.jit(lambda p, toks, c, batch: model_lib.prefill(
-        p, cfg, toks, c, _batch_extras(cfg, batch)),
-        static_argnames=("batch",))
+    def prefill(p, toks, c, batch):
+        return model_lib.prefill(p, cfg, toks, c, _batch_extras(cfg, batch))
+    return jax.jit(prefill, static_argnames=("batch",))
+
+
+def sample(logits, seeds, counts, temps, topk, topp):
+    """The synchronous loop's sampler program (``sampler.sample_batch``)."""
+    return sampler.sample_batch(logits, seeds, counts, temps, topk, topp)
 
 
 # swap ops retrace per page-id bucket (power-of-two padded with the null
 # page), so the trace count stays O(log pool) like the prefill buckets
 _jit_swap_out = jax.jit(model_lib.swap_out_pages)
 _jit_swap_in = jax.jit(model_lib.swap_in_pages)
-_jit_sample = jax.jit(sampler.sample_batch)
+_jit_sample = jax.jit(sample)
 
 
 # the overlapped loop's fused decode+sample step: ONE jitted dispatch per
@@ -366,27 +381,27 @@ _jit_sample = jax.jit(sampler.sample_batch)
 # the CPU tests exercise the same buffer lifetimes as the chip
 @functools.lru_cache(maxsize=None)
 def _jit_decode_sample_paged(cfg: ModelConfig):
-    def step(p, tok_host, tok_dev, use_dev, c, a, seeds, counts, temps,
-             topk, topp, greedy_only):
+    def decode_sample_paged(p, tok_host, tok_dev, use_dev, c, a, seeds,
+                            counts, temps, topk, topp, greedy_only):
         return model_lib.decode_and_sample_paged(
             p, cfg, tok_host, tok_dev, use_dev, c, a,
             lambda lg: sampler.fused_sample(
                 lg, seeds, counts, temps, topk, topp,
                 greedy_only=greedy_only))
-    return jax.jit(step, static_argnames=("greedy_only",),
+    return jax.jit(decode_sample_paged, static_argnames=("greedy_only",),
                    donate_argnums=(4,))
 
 
 @functools.lru_cache(maxsize=None)
 def _jit_decode_sample(cfg: ModelConfig):
-    def step(p, tok_host, tok_dev, use_dev, c, seeds, counts, temps,
-             topk, topp, greedy_only):
+    def decode_sample(p, tok_host, tok_dev, use_dev, c, seeds, counts, temps,
+                      topk, topp, greedy_only):
         return model_lib.decode_and_sample(
             p, cfg, tok_host, tok_dev, use_dev, c,
             lambda lg: sampler.fused_sample(
                 lg, seeds, counts, temps, topk, topp,
                 greedy_only=greedy_only))
-    return jax.jit(step, static_argnames=("greedy_only",),
+    return jax.jit(decode_sample, static_argnames=("greedy_only",),
                    donate_argnums=(4,))
 
 
@@ -430,7 +445,6 @@ class EngineStats:
     decode_dispatches: int = 0
     tokens_out: int = 0
     straggler_events: int = 0
-    wall_decode_s: float = 0.0
     admitted: int = 0
     completed: int = 0
     mode: str = ""
@@ -460,10 +474,19 @@ class EngineStats:
     requests_replayed: int = 0  # requests re-dispatched by failover
     tokens_replayed: int = 0   # re-decoded tokens suppressed as duplicates
     heartbeat_misses: int = 0  # reply deadlines blown (straggle signal)
+    # prefill padding: prompt positions prefilled, and positions the
+    # prefill programs computed (batched: rows x bucket, padding rows and
+    # positions included; chunked: the chunk's bucket); the pad is the
+    # difference
+    prefill_tokens: int = 0
+    prefill_computed_tokens: int = 0
     # per-request latency samples, appended at completion
     admission_wait_s: list = dataclasses.field(default_factory=list)
     ttft_s: list = dataclasses.field(default_factory=list)
     latency_s: list = dataclasses.field(default_factory=list)
+    # per request, first token sampled -> its event drained from the engine
+    # (``Request.t_first_out - t_first_token``), appended at the drain
+    first_token_hold_s: list = dataclasses.field(default_factory=list)
 
     def percentiles(self, series: str = "latency_s",
                     qs: tuple = (50, 90, 99)) -> dict:
@@ -483,6 +506,14 @@ class EngineStats:
              f"admission p50/p99={adm['p50']:.3f}/{adm['p99']:.3f}s")
         if self.prefill_chunks:
             s += f" prefill_chunks={self.prefill_chunks}"
+        if self.prefill_computed_tokens:
+            pad = 1 - self.prefill_tokens / self.prefill_computed_tokens
+            s += (f" prefill tokens={self.prefill_tokens} "
+                  f"computed={self.prefill_computed_tokens} pad={pad:.1%}")
+        if self.first_token_hold_s:
+            hold = self.percentiles("first_token_hold_s", (50, 99))
+            s += (f" first-token hold p50/p99="
+                  f"{hold['p50']:.3f}/{hold['p99']:.3f}s")
         if self.kv_spill_pages or self.pool_exhausted or self.rejected:
             s += (f" pool_exhausted={self.pool_exhausted} "
                   f"rejected={self.rejected} preempt={self.preemptions} "
@@ -577,6 +608,8 @@ class EngineCore:
         self.queue: list[Request] = []
         self.slots: list[Optional[Request]] = [None] * max_batch
         self._events: list[RequestOutput] = []
+        # requests whose first token's event awaits the next drain
+        self._first_out: list[Request] = []
         self._chunk_ok = (mode == "continuous"
                           and model_lib.supports_chunked_prefill(cfg))
         self._px: Optional[PrefixIndex] = None  # set in the continuous branch
@@ -926,6 +959,8 @@ class EngineCore:
             half = self.MAX_PENDING_EVENTS // 2
             finals = [e for e in self._events[:half] if e.finished]
             self._events = finals + self._events[half:]
+        if token is not None and len(req.out_tokens) == 1:
+            self._first_out.append(req)  # stamped by drain_outputs
         sched = None
         ttft = lat = None
         if finished:
@@ -941,8 +976,18 @@ class EngineCore:
             ttft_s=ttft, latency_s=lat, sched=sched))
 
     def drain_outputs(self) -> list[RequestOutput]:
-        """Pop all RequestOutput events accumulated since the last drain."""
+        """Pop all RequestOutput events accumulated since the last drain.
+
+        The drain is where a request's first token leaves the engine: it
+        stamps ``t_first_out`` and records the hold since the token was
+        sampled (``EngineStats.first_token_hold_s``)."""
         ev, self._events = self._events, []
+        if self._first_out:
+            now = time.monotonic()
+            for req in self._first_out:
+                req.t_first_out = now
+                self.stats.first_token_hold_s.append(now - req.t_first_token)
+            self._first_out = []
         return ev
 
     def step(self) -> list[RequestOutput]:
@@ -977,8 +1022,14 @@ class EngineCore:
         stochastic row switches the whole batch to the vectorized
         ``sampler.sample_batch`` (greedy rows still argmax inside it).
         """
+        with spans.span(spans.SAMPLE):
+            tok = self._sample_dispatch(logits, items)
+        with spans.span(spans.READBACK):
+            return np.asarray(tok)
+
+    def _sample_dispatch(self, logits, items: list[tuple[int, Request]]):
         if all(it[1].sampling.temperature <= 0.0 for it in items):
-            return np.asarray(sampler.greedy(logits))
+            return sampler.greedy(logits)
         b = logits.shape[0]
         temps = np.zeros((b,), np.float32)
         seeds = np.zeros((b,), np.int32)
@@ -992,8 +1043,7 @@ class EngineCore:
             counts[row] = len(req.out_tokens)
             topk[row] = sp.top_k
             topp[row] = sp.top_p
-        return np.asarray(_jit_sample(logits, seeds, counts, temps, topk,
-                                      topp))
+        return _jit_sample(logits, seeds, counts, temps, topk, topp)
 
     # ------------------------------------------------------------------
     # scheduler views
@@ -1566,6 +1616,56 @@ class EngineCore:
         the policy's chunk budget claim their slot and pages now and
         prefill chunk-by-chunk over the following steps.  Occupied slots
         keep their decode state untouched throughout."""
+        with spans.span(spans.ADMIT):
+            group, now = self._claim_slots()
+            if not group:
+                return
+            toks, slot_ids, true_lens, computed = self._group_prompts(group)
+        with spans.span(spans.PREFILL):
+            logits, out_cache = self._prefill_slots(
+                self.params, toks, true_lens,
+                {**self.cache, "block": self.block}, slot_ids)
+        out_cache.pop("block")  # authoritative copy stays host-side
+        self.cache = out_cache
+        self.stats.prefills += 1
+        self.stats.admitted += len(group)
+        self.stats.prefill_tokens += sum(len0 for i, req, len0 in group)
+        self.stats.prefill_computed_tokens += computed
+        toks_out = self._sample_rows(
+            logits, [(row, req) for row, (i, req, len0) in enumerate(group)])
+        logits_np = None
+        if self._px is not None:
+            with spans.span(spans.READBACK):
+                logits_np = np.asarray(logits)
+        t1 = time.monotonic()
+        with spans.span(spans.EMIT):
+            for row, ((i, req, len0), tok) in enumerate(zip(group, toks_out)):
+                tok = int(tok)
+                if req.t_admit == 0.0:  # restarts keep first-admit times
+                    req.t_admit = now
+                    req.t_first_token = t1
+                req.out_tokens.append(tok)
+                self.stats.tokens_out += 1
+                self.last_np[i] = tok
+                self.slot_len[i] = len0
+                self.slots[i] = req
+                if self._px is not None:
+                    # register BEFORE any finish below: the pages must
+                    # outlive the slot as cached entries even for one-token
+                    # requests
+                    self._px_register_prompt(i, req, len0, logits_np[row])
+                reason = self._finish_reason_for(req, tok, len0)
+                if reason is not None:
+                    self._finish(i, req, reason, token=tok)
+                else:
+                    self._emit(req, tok)
+
+    def _claim_slots(self) -> tuple[list, float]:
+        """Admission's host half: the scheduler's plan, its preemptions,
+        and a free slot plus pages for each request it admits.  Prefix hits
+        and chunked prompts are admitted here outright; returns the group of
+        ``(slot, request, cache length)`` for the one-shot batched prefill,
+        and the admission instant."""
         plan = self.scheduler.admit(list(self.queue), self._views(),
                                     self.allocator.available)
         head = next((r for r in plan.order if r in self.queue), None)
@@ -1639,8 +1739,12 @@ class EngineCore:
                 self.stats.admitted += 1
             else:
                 group.append((i, req, len0))
-        if not group:
-            return
+        return group, now
+
+    def _group_prompts(self, group: list):
+        """The batched prefill's inputs for ``group``: prompts padded to a
+        common bucket, slot ids and true lengths, and the positions the
+        prefill will compute (rows x (bucket + vision tokens))."""
         # common bucket for the group, capped so bucket + vision tokens still
         # fits a slot's block-table row (tail-pad pages beyond an allocation
         # fall on the null page, but the row itself must not overflow)
@@ -1658,36 +1762,7 @@ class EngineCore:
              for i, req, len0 in rows], np.int32)
         slot_ids = np.asarray([i for i, req, len0 in rows], np.int32)
         true_lens = np.asarray([len0 for i, req, len0 in rows], np.int32)
-        logits, out_cache = self._prefill_slots(
-            self.params, toks, true_lens, {**self.cache, "block": self.block},
-            slot_ids)
-        out_cache.pop("block")  # authoritative copy stays host-side
-        self.cache = out_cache
-        self.stats.prefills += 1
-        self.stats.admitted += len(group)
-        toks_out = self._sample_rows(
-            logits, [(row, req) for row, (i, req, len0) in enumerate(group)])
-        logits_np = np.asarray(logits) if self._px is not None else None
-        t1 = time.monotonic()
-        for row, ((i, req, len0), tok) in enumerate(zip(group, toks_out)):
-            tok = int(tok)
-            if req.t_admit == 0.0:  # restarts keep their first-admit times
-                req.t_admit = now
-                req.t_first_token = t1
-            req.out_tokens.append(tok)
-            self.stats.tokens_out += 1
-            self.last_np[i] = tok
-            self.slot_len[i] = len0
-            self.slots[i] = req
-            if self._px is not None:
-                # register BEFORE any finish below: the pages must outlive
-                # the slot as cached entries even for one-token requests
-                self._px_register_prompt(i, req, len0, logits_np[row])
-            reason = self._finish_reason_for(req, tok, len0)
-            if reason is not None:
-                self._finish(i, req, reason, token=tok)
-            else:
-                self._emit(req, tok)
+        return toks, slot_ids, true_lens, len(rows) * (bucket + extra)
 
     def _prefill_chunks(self) -> int:
         """Run one prefill chunk for every mid-prefill slot (the policy's
@@ -1700,26 +1775,31 @@ class EngineCore:
                 continue
             len0 = self._cache_len0(req)
             pos = self.prefill_pos[i]
-            budget = max(1, int(self.scheduler.prefill_budget(
-                self._slot_view(i))))
-            clen = min(budget, len0 - pos)
-            cap = self.pages_per_slot * self.page_size
-            # pad the chunk to a power-of-two bucket (floor = page size):
-            # per-step compute scales with the BUDGET, not the slot
-            # capacity, and the trace count stays O(log max_seq) like the
-            # group-prefill buckets.  Bit-identity is per-position, so the
-            # bucket shape is free to vary (tests/test_chunked_prefill.py
-            # pins identity across differently-bucketed schedules).
-            cb = min(prefill_bucket(clen, floor=self.page_size), cap)
-            toks = np.zeros((cb,), np.int32)
-            toks[:clen] = req.prompt[pos:pos + clen]
-            logits, out_cache = self._prefill_chunk(
-                self.params, toks, np.int32(pos), np.int32(clen),
-                {**self.cache, "block": self.block}, np.int32(i))
+            with spans.span(spans.ADMIT):
+                budget = max(1, int(self.scheduler.prefill_budget(
+                    self._slot_view(i))))
+                clen = min(budget, len0 - pos)
+                cap = self.pages_per_slot * self.page_size
+                # pad the chunk to a power-of-two bucket (floor = page
+                # size): per-step compute scales with the BUDGET, not the
+                # slot capacity, and the trace count stays O(log max_seq)
+                # like the group-prefill buckets.  Bit-identity is
+                # per-position, so the bucket shape is free to vary
+                # (tests/test_chunked_prefill.py pins identity across
+                # differently-bucketed schedules).
+                cb = min(prefill_bucket(clen, floor=self.page_size), cap)
+                toks = np.zeros((cb,), np.int32)
+                toks[:clen] = req.prompt[pos:pos + clen]
+            with spans.span(spans.PREFILL):
+                logits, out_cache = self._prefill_chunk(
+                    self.params, toks, np.int32(pos), np.int32(clen),
+                    {**self.cache, "block": self.block}, np.int32(i))
             out_cache.pop("block")
             self.cache = out_cache
             req.n_chunks += 1
             self.stats.prefill_chunks += 1
+            self.stats.prefill_tokens += clen
+            self.stats.prefill_computed_tokens += cb
             ran += 1
             pos += clen
             self.prefill_pos[i] = pos
@@ -1731,16 +1811,17 @@ class EngineCore:
                                              np.asarray(logits))
                 tok = int(self._sample_rows(
                     jnp.asarray(logits)[None], [(0, req)])[0])
-                if req.t_first_token == 0.0:
-                    req.t_first_token = time.monotonic()
-                req.out_tokens.append(tok)
-                self.stats.tokens_out += 1
-                self.last_np[i] = tok
-                reason = self._finish_reason_for(req, tok, pos)
-                if reason is not None:
-                    self._finish(i, req, reason, token=tok)
-                else:
-                    self._emit(req, tok)
+                with spans.span(spans.EMIT):
+                    if req.t_first_token == 0.0:
+                        req.t_first_token = time.monotonic()
+                    req.out_tokens.append(tok)
+                    self.stats.tokens_out += 1
+                    self.last_np[i] = tok
+                    reason = self._finish_reason_for(req, tok, pos)
+                    if reason is not None:
+                        self._finish(i, req, reason, token=tok)
+                    else:
+                        self._emit(req, tok)
         return ran
 
     def _ensure_pages(self) -> None:
@@ -1820,10 +1901,15 @@ class EngineCore:
             # the step is about to be read back: its numpy args must be
             # bit-identical to what was dispatched (aliasing guard)
             self._san.check_drain(pend["san"])
-        tok_np = np.asarray(pend["tok"])  # blocks on THIS step only; any
-        # younger dispatch keeps running behind it
+        with spans.span(spans.READBACK):
+            tok_np = np.asarray(pend["tok"])  # blocks on THIS step only;
+            # any younger dispatch keeps running behind it
+        with spans.span(spans.EMIT):
+            self._account_rows(pend["rows"], tok_np)
+
+    def _account_rows(self, rows: list, tok_np: np.ndarray) -> None:
         if self.mode == "continuous":
-            for i, req, seq_after, epoch in pend["rows"]:
+            for i, req, seq_after, epoch in rows:
                 if (req.done or self.slots[i] is not req
                         or self._slot_epoch[i] != epoch):
                     continue  # slot reassigned/released since dispatch
@@ -1839,7 +1925,7 @@ class EngineCore:
                 else:
                     self._emit(req, t)
         else:
-            for i, req, seq_after in pend["rows"]:
+            for i, req, seq_after in rows:
                 if req.done or self.slots[i] is not req:
                     continue
                 t = int(tok_np[i])
@@ -1871,7 +1957,6 @@ class EngineCore:
         old, self._pending = self._pending, None
         tok_dev = (old["tok"] if old is not None
                    else np.zeros((self.max_batch,), np.int32))
-        t0 = time.monotonic()
         # numpy args MUST be snapshotted: on the CPU backend jit wraps host
         # buffers zero-copy, so the async-executing step would otherwise read
         # ``last_np`` / ``block`` concurrently with the in-place mutations
@@ -1879,13 +1964,11 @@ class EngineCore:
         last_np = self.last_np.copy()
         block = self.block.copy()
         active = np.asarray(active_list)
-        tok, cache = self._decode_sample(
-            self.params, last_np, tok_dev, use_dev,
-            {**self.cache, "block": block},
-            active, *sp_rows, greedy_only=greedy_only)
-        # wall_decode_s measures DISPATCH time here (the compute itself is
-        # deliberately not awaited); bench wall clocks stay end-to-end
-        self.stats.wall_decode_s += time.monotonic() - t0
+        with spans.span(spans.DECODE):
+            tok, cache = self._decode_sample(
+                self.params, last_np, tok_dev, use_dev,
+                {**self.cache, "block": block},
+                active, *sp_rows, greedy_only=greedy_only)
         san = None
         if self._san is not None:
             san = self._san.guard_dispatch(
@@ -1965,36 +2048,37 @@ class EngineCore:
             return True
         active = np.asarray(active_list)
         pre_cache = {**self.cache, "block": self.block}  # for re-dispatch
-        t0 = time.monotonic()
-        logits, cache = self._decode(self.params, self.last_np, pre_cache,
-                                     active)
-        dt = time.monotonic() - t0
-        if self.watchdog is not None and self.watchdog(
-                self.stats.decode_steps, dt):
-            self.stats.straggler_events += 1
+        with spans.span(spans.DECODE):
+            t0 = time.monotonic()
             logits, cache = self._decode(self.params, self.last_np,
                                          pre_cache, active)
+            dt = time.monotonic() - t0
+            if self.watchdog is not None and self.watchdog(
+                    self.stats.decode_steps, dt):
+                self.stats.straggler_events += 1
+                logits, cache = self._decode(self.params, self.last_np,
+                                             pre_cache, active)
         cache.pop("block")  # authoritative copy stays host-side
         self.cache = cache
         self.stats.decode_steps += 1
         self.stats.decode_dispatches += 2  # decode + separate sample
-        self.stats.wall_decode_s += dt
         tok_np = self._sample_rows(  # one sync per step
             logits, [(i, r) for i, r in enumerate(self.slots)
                      if r is not None and active_list[i]])
-        for i, req in enumerate(self.slots):
-            if req is None or not active_list[i]:
-                continue
-            t = int(tok_np[i])
-            self.last_np[i] = t
-            req.out_tokens.append(t)
-            self.stats.tokens_out += 1
-            self.slot_len[i] += 1
-            reason = self._finish_reason_for(req, t, self.slot_len[i])
-            if reason is not None:
-                self._finish(i, req, reason, token=t)
-            else:
-                self._emit(req, t)
+        with spans.span(spans.EMIT):
+            for i, req in enumerate(self.slots):
+                if req is None or not active_list[i]:
+                    continue
+                t = int(tok_np[i])
+                self.last_np[i] = t
+                req.out_tokens.append(t)
+                self.stats.tokens_out += 1
+                self.slot_len[i] += 1
+                reason = self._finish_reason_for(req, t, self.slot_len[i])
+                if reason is not None:
+                    self._finish(i, req, reason, token=t)
+                else:
+                    self._emit(req, t)
         return True
 
     # ------------------------------------------------------------------
@@ -2029,10 +2113,13 @@ class EngineCore:
             + [[0] * plen] * (self.max_batch - len(wave)), jnp.int32)
         self.cache = model_lib.init_cache(self.cfg, self.max_batch,
                                           self.max_seq)
-        logits, self.cache = _jit_prefill(self.cfg)(
-            self.params, toks, self.cache, self.max_batch)
+        with spans.span(spans.PREFILL):
+            logits, self.cache = _jit_prefill(self.cfg)(
+                self.params, toks, self.cache, self.max_batch)
         self.stats.prefills += 1
         self.stats.admitted += len(wave)
+        self.stats.prefill_tokens += sum(len(r.prompt) for r in wave)
+        self.stats.prefill_computed_tokens += self.max_batch * plen
         tok_np = self._sample_rows(
             logits, [(row, r) for row, r in enumerate(wave)])
         self.last_token = jnp.asarray(tok_np)
@@ -2077,14 +2164,13 @@ class EngineCore:
         use_dev = np.asarray([i in live for i in range(self.max_batch)])
         tok_dev = (old["tok"] if old is not None
                    else np.zeros((self.max_batch,), np.int32))
-        t0 = time.monotonic()
         # snapshot: CPU jit aliases numpy inputs zero-copy and the drain
         # below mutates ``_wave_last_np`` while this step is still running
         wave_last = self._wave_last_np.copy()
-        tok, cache = self._decode_sample(
-            self.params, wave_last, tok_dev, use_dev,
-            self.cache, *sp_rows, greedy_only=greedy_only)
-        self.stats.wall_decode_s += time.monotonic() - t0
+        with spans.span(spans.DECODE):
+            tok, cache = self._decode_sample(
+                self.params, wave_last, tok_dev, use_dev,
+                self.cache, *sp_rows, greedy_only=greedy_only)
         san = None
         if self._san is not None:
             san = self._san.guard_dispatch(
@@ -2109,18 +2195,19 @@ class EngineCore:
             self._overlap_round_wave()
             return True
         pre_cache = self.cache
-        t0 = time.monotonic()
-        logits, cache = self._decode(self.params, self.last_token, pre_cache)
-        dt = time.monotonic() - t0
-        if self.watchdog is not None and self.watchdog(
-                self.stats.decode_steps, dt):
-            self.stats.straggler_events += 1
+        with spans.span(spans.DECODE):
+            t0 = time.monotonic()
             logits, cache = self._decode(self.params, self.last_token,
                                          pre_cache)
+            dt = time.monotonic() - t0
+            if self.watchdog is not None and self.watchdog(
+                    self.stats.decode_steps, dt):
+                self.stats.straggler_events += 1
+                logits, cache = self._decode(self.params, self.last_token,
+                                             pre_cache)
         self.cache = cache
         self.stats.decode_steps += 1
         self.stats.decode_dispatches += 2  # decode + separate sample
-        self.stats.wall_decode_s += dt
         tok_np = self._sample_rows(
             logits, [(i, r) for i, r in enumerate(self.slots)
                      if r is not None])
@@ -2147,9 +2234,10 @@ class EngineCore:
     # ------------------------------------------------------------------
     def _advance(self) -> bool:
         """Admit + one decode step over the active batch; True if any work."""
-        if self.mode == "continuous":
-            return self._step_continuous()
-        return self._step_wave()
+        with spans.span(spans.STEP):
+            if self.mode == "continuous":
+                return self._step_continuous()
+            return self._step_wave()
 
     def run(self, max_steps: int = 10_000) -> EngineStats:
         steps = 0

@@ -69,7 +69,7 @@ WIRE_FIELDS = {
         "deadline_s", "session", "sampling", "temperature", "out_tokens",
         "done", "rejected", "finish_reason", "n_folded", "n_chunks",
         "n_preempted", "n_migrated", "t_submit", "t_admit",
-        "t_first_token", "t_done",
+        "t_first_token", "t_done", "t_first_out",
     ),
     "SamplingParams": ("temperature", "top_k", "top_p", "seed"),
     "RequestOutput": (
