@@ -1,10 +1,12 @@
-"""jit'd wrappers for the decode-attention kernel.
+"""jit'd wrappers for the decode-attention kernels.
 
 ``decode_attention_op`` pads Smax to the kv block and accepts either a shared
-scalar cursor or a per-slot lengths vector [B] (continuous batching).
-``paged_decode_attention_op`` is the block-table front-end: it gathers each
-slot's pages from the shared page pool into the contiguous [B, Smax] layout
-the kernel streams over, then masks per-slot valid lengths.
+scalar cursor or a per-slot lengths vector [B] over a contiguous cache.
+``paged_decode_attention_op`` is the block-table kernel (``paged.py``,
+q: [B, H, D]; k/v_pages: [P, page, Hkv, D]; block_table: [B, pages_per_slot];
+lengths: [B]): it reads each slot's pages straight from the shared pool, only
+as far as the slot's length.  It is the one on the served path:
+``models.attention.paged_decode_attention`` lowers to it on a TPU.
 """
 
 from __future__ import annotations
@@ -15,6 +17,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.decode_attention.decode_attention import gqa_decode_attention
+from repro.kernels.decode_attention.paged import (  # noqa: F401
+    paged_decode_attention_kernel as paged_decode_attention_op)
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
@@ -30,20 +34,3 @@ def decode_attention_op(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         v_cache = jnp.pad(v_cache, ((0, 0), (0, pad), (0, 0), (0, 0)))
     return gqa_decode_attention(q, k_cache, v_cache, length, block_k=bk,
                                 interpret=interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
-def paged_decode_attention_op(q: jax.Array, k_pages: jax.Array,
-                              v_pages: jax.Array, block_table: jax.Array,
-                              lengths: jax.Array, block_k: int = 512,
-                              interpret: bool = False) -> jax.Array:
-    """Paged decode attention over a shared page pool.
-
-    q: [B, H, D]; k/v_pages: [P, page, Hkv, D]; block_table: [B, pages_per
-    slot] int32 page ids; lengths: [B] valid tokens per slot.
-    """
-    from repro.models.attention import gather_paged_kv
-
-    k, v = gather_paged_kv(k_pages, v_pages, block_table)
-    return decode_attention_op(q, k, v, lengths, block_k=block_k,
-                               interpret=interpret)

@@ -1,8 +1,12 @@
 """Attention: chunked (flash-style) full attention + single-token decode.
 
-Pure-jnp implementations used by every model and by the dry-run lowering;
-the Pallas kernels in kernels/flash_attention and kernels/decode_attention
-are the TPU hot-path versions validated against these in tests.
+Pure-jnp implementations used by every model and by the dry-run lowering.
+One Pallas kernel is on the served path: lowered for a TPU,
+``paged_decode_attention`` over a plain page pool runs the block-table
+kernel in kernels/decode_attention/paged.py, checked against the jnp path in
+tests.  int8 pools, the MLA decode, chunked-prefill and full-sequence
+attention stay on the jnp code here; kernels/flash_attention and the
+contiguous-cache decode kernel are not called from models/.
 
 Memory discipline: scores materialize only per (q_chunk, kv_chunk) block via
 a double ``lax.scan`` with online softmax, so prefill_32k fits. Causality is
@@ -218,16 +222,36 @@ def gather_paged_kv(k_pages: jax.Array, v_pages: jax.Array,
             gather_paged_rows(v_pages, block_table))
 
 
-def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
-                           v_pages: jax.Array, block_table: jax.Array,
+def paged_decode_attention_jnp(q: jax.Array, k_pages, v_pages,
+                               block_table: jax.Array,
+                               lengths: jax.Array) -> jax.Array:
+    """``paged_decode_attention`` in jnp: gather each slot's whole block row
+    (dequantized for int8 pools), then ``decode_attention``."""
+    k, v = gather_paged_kv(k_pages, v_pages, block_table)
+    return decode_attention(q, k, v, lengths)
+
+
+def paged_decode_attention(q: jax.Array, k_pages, v_pages,
+                           block_table: jax.Array,
                            lengths: jax.Array) -> jax.Array:
     """One-token attention against a paged cache.
 
-    q: [B, H, D]; k/v_pages: [P, page, Hkv, D]; block_table: [B,
-    pages_per_slot]; lengths: [B] valid tokens per slot (new token included).
+    q: [B, H, D]; k/v_pages: [P, page, Hkv, D] (or int8 ``(data, scale)``
+    pools); block_table: [B, pages_per_slot]; lengths: [B] valid tokens per
+    slot (new token included).
+
+    Lowered for a TPU, a plain pool runs the Pallas kernel that reads only
+    each slot's live pages (``kernels/decode_attention/paged.py``); every
+    other platform, and int8 pools, run ``paged_decode_attention_jnp``.
     """
-    k, v = gather_paged_kv(k_pages, v_pages, block_table)
-    return decode_attention(q, k, v, lengths)
+    if isinstance(k_pages, tuple):
+        return paged_decode_attention_jnp(q, k_pages, v_pages, block_table,
+                                          lengths)
+    from repro.kernels.decode_attention.paged import (
+        paged_decode_attention_kernel)
+    return jax.lax.platform_dependent(
+        q, k_pages, v_pages, block_table, lengths,
+        tpu=paged_decode_attention_kernel, default=paged_decode_attention_jnp)
 
 
 def paged_chunk_attention(q: jax.Array, k_pages: jax.Array,

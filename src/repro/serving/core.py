@@ -480,6 +480,12 @@ class EngineStats:
     # difference
     prefill_tokens: int = 0
     prefill_computed_tokens: int = 0
+    # paged decode KV reads: per decode step, the pages holding each active
+    # slot's attended positions (``ceil(len / page)``, the new token
+    # included), and the block table's size (slots x pages per slot) that a
+    # read of every slot's full row would touch
+    decode_kv_live_pages: int = 0
+    decode_kv_table_pages: int = 0
     # per-request latency samples, appended at completion
     admission_wait_s: list = dataclasses.field(default_factory=list)
     ttft_s: list = dataclasses.field(default_factory=list)
@@ -510,6 +516,10 @@ class EngineStats:
             pad = 1 - self.prefill_tokens / self.prefill_computed_tokens
             s += (f" prefill tokens={self.prefill_tokens} "
                   f"computed={self.prefill_computed_tokens} pad={pad:.1%}")
+        if self.decode_kv_table_pages:
+            live = self.decode_kv_live_pages / self.decode_kv_table_pages
+            s += (f" kv pages live={self.decode_kv_live_pages}"
+                  f"/{self.decode_kv_table_pages} ({live:.1%})")
         if self.first_token_hold_s:
             hold = self.percentiles("first_token_hold_s", (50, 99))
             s += (f" first-token hold p50/p99="
@@ -1981,6 +1991,7 @@ class EngineCore:
         self.cache = cache
         self.stats.decode_steps += 1
         self.stats.decode_dispatches += 1
+        self._count_kv_pages(active_list)
         rows = []
         for i, req in items:
             seq_after = self.slot_len[i] + self._inflight[i] + 1
@@ -1989,6 +2000,16 @@ class EngineCore:
         self._pending = {"tok": tok, "rows": rows, "san": san}
         if old is not None:
             self._drain_rows(old)
+
+    def _count_kv_pages(self, active_list: list[bool]) -> None:
+        """Count one paged decode step's KV reads: each active slot attends
+        its dispatched length (drained + in flight) plus the new token."""
+        self.stats.decode_kv_live_pages += sum(
+            pages_needed(self.slot_len[i] + self._inflight[i] + 1,
+                         self.page_size)
+            for i in range(self.max_batch) if active_list[i])
+        self.stats.decode_kv_table_pages += (self.max_batch
+                                             * self.pages_per_slot)
 
     def _mask_predicted_finishes(self, active_list: list[bool]) -> None:
         """Exclude slots whose undrained token already finishes them.
@@ -2062,6 +2083,7 @@ class EngineCore:
         self.cache = cache
         self.stats.decode_steps += 1
         self.stats.decode_dispatches += 2  # decode + separate sample
+        self._count_kv_pages(active_list)
         tok_np = self._sample_rows(  # one sync per step
             logits, [(i, r) for i, r in enumerate(self.slots)
                      if r is not None and active_list[i]])

@@ -3,9 +3,9 @@
 The TPU compiler refuses what interpret mode accepts: block shapes off the
 (8, 128) tiling, reshapes Mosaic cannot lay out, primitives it cannot lower,
 programs that outgrow the chip.  These tests compile each Pallas kernel with
-``interpret=False`` at real widths, and smollm-360m's paged decode step from
-``eval_shape`` shapes, for one chip of a ``v5e:2x2`` topology.  Nothing runs,
-so they say nothing about results or times.
+``interpret=False`` at real widths, and smollm-360m's paged decode step at
+the cell's size from ``eval_shape`` shapes, for one chip of a ``v5e:2x2``
+topology.  Nothing runs, so they say nothing about results or times.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and every test worker
@@ -13,6 +13,7 @@ imports this file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -58,7 +59,8 @@ def _compile(fn, *args):
 
 
 def _kernel_cases():
-    from repro.kernels.decode_attention.ops import decode_attention_op
+    from repro.kernels.decode_attention.ops import (decode_attention_op,
+                                                    paged_decode_attention_op)
     from repro.kernels.ecc_decode.ecc_decode import ecc_decode_pages
     from repro.kernels.flash_attention.ops import flash_attention_op
     from repro.kernels.int8_pagegemv.int8_pagegemv import paged_int8_gemm
@@ -73,6 +75,13 @@ def _kernel_cases():
         "decode_attention": (decode_attention_op, [
             ((4, HEADS, D_HEAD), bf16), ((4, 512, KV_HEADS, D_HEAD), bf16),
             ((4, 512, KV_HEADS, D_HEAD), bf16), ((4,), i32)]),
+        # the cell's paged decode read: 16 slots x 128 pages of 16 over a
+        # 2049-page pool, smollm-360m heads
+        "paged_decode_attention": (paged_decode_attention_op, [
+            ((16, HEADS, D_HEAD), bf16),
+            ((2049, 16, KV_HEADS, D_HEAD), bf16),
+            ((2049, 16, KV_HEADS, D_HEAD), bf16), ((16, 128), i32),
+            ((16,), i32)]),
         # smollm-360m prefill of a 256-token prompt
         "flash_attention": (flash_attention_op, [
             ((1, HEADS, 256, D_HEAD), bf16), ((1, KV_HEADS, 256, D_HEAD), bf16),
@@ -96,7 +105,8 @@ def _kernel_cases():
     }
 
 
-@pytest.mark.parametrize("name", ["decode_attention", "flash_attention",
+@pytest.mark.parametrize("name", ["decode_attention",
+                                  "paged_decode_attention", "flash_attention",
                                   "int8_pagegemv", "w4a16_gemv", "ecc_decode",
                                   "ssd_scan"])
 def test_kernel_compiles_for_v5e(name, one_chip):
@@ -105,10 +115,26 @@ def test_kernel_compiles_for_v5e(name, one_chip):
     _compile(lambda *a: fn(*a, interpret=False), *args)
 
 
+@pytest.mark.parametrize("h,hkv,d", [
+    (64, 8, 128), (48, 8, 128), (32, 2, 128), (16, 16, 128), (32, 32, 112),
+    (96, 8, 128)])
+def test_paged_decode_attention_compiles_for_registry_heads(h, hkv, d,
+                                                           one_chip):
+    """The other paged GQA head shapes of the registry (dense, moe, vlm,
+    hybrid), 8 slots x 128 pages of 16."""
+    from repro.kernels.decode_attention.ops import paged_decode_attention_op
+
+    shapes = [((8, h, d), jnp.bfloat16), ((1025, 16, hkv, d), jnp.bfloat16),
+              ((1025, 16, hkv, d), jnp.bfloat16), ((8, 128), jnp.int32),
+              ((8,), jnp.int32)]
+    args = [jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in shapes]
+    _compile(paged_decode_attention_op, *args)
+
+
 def test_smollm_paged_decode_step_compiles_for_v5e(one_chip):
     from repro.serving.core import _jit_decode_paged
 
-    cfg, max_batch, max_seq, page = SMOLLM, 4, 512, 16
+    cfg, max_batch, max_seq, page = SMOLLM, 16, 2048, 16
     params = jax.eval_shape(lambda: quantize_params(model_lib.init_params(
         cfg, jax.random.PRNGKey(0), max_seq=max_seq), mode="w8a8"))
     cache = jax.eval_shape(lambda: model_lib.init_paged_cache(
@@ -121,6 +147,11 @@ def test_smollm_paged_decode_step_compiles_for_v5e(one_chip):
         _on(cache, one_chip),
         jax.ShapeDtypeStruct((max_batch,), jnp.bool_, sharding=one_chip),
     ).compile()
+    # the paged attention read lowers to the Pallas kernel on the chip, and
+    # no f32 copy of every slot's full block row is left in the step
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert not re.search(r"f32\[[0-9,]*\b2048,[0-9,]*\]", text)
     mem = compiled.memory_analysis()
     # weights + KV pool + step temporaries fit one 16 GB chip
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes

@@ -128,8 +128,8 @@ def test_decode_attention_lengths_vector(b, h, hkv, smax, d):
 
 
 def test_paged_decode_attention_matches_dense():
-    """Block-table gather + lengths masking == dense cache with the same
-    contents; slots point at scattered pages of a shared pool."""
+    """The block-table kernel == dense cache with the same contents; slots
+    point at scattered pages of a shared pool."""
     from repro.kernels.decode_attention.ops import paged_decode_attention_op
     from repro.kernels.decode_attention.ref import decode_attention_ref
 
@@ -144,12 +144,82 @@ def test_paged_decode_attention_matches_dense():
     q = jax.random.normal(k3, (b, h, d), jnp.float32)
     lens = jnp.asarray([page * pps, 7, 23], jnp.int32)
     out = paged_decode_attention_op(q, k_pages, v_pages, block, lens,
-                                    block_k=32, interpret=True)
+                                    interpret=True)
     k_dense = k_pages[block].reshape(b, pps * page, hkv, d)
     v_dense = v_pages[block].reshape(b, pps * page, hkv, d)
     ref = decode_attention_ref(q, k_dense, v_dense, lens)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+
+PAGE = 16
+# lengths 0, 1, page-1, page, page+1, mid-table and full capacity
+WALK_LENS = (0, 1, PAGE - 1, PAGE, PAGE + 1, 21 * PAGE + 5, 40 * PAGE)
+
+
+def _walk_pools(h, hkv, d, dtype, pps=40):
+    """An interleaved block table over a pool whose every row past a
+    slot's length, and every unallocated page, is NaN; the same pool with
+    those rows zeroed; and the queries."""
+    b = len(WALK_LENS)
+    n_pages = b * pps + 3              # three pages no slot owns
+    k1, k2, k3 = jax.random.split(jax.random.fold_in(KEY, h * d + hkv), 3)
+    block = np.arange(1, b * pps + 1, dtype=np.int32).reshape(pps, b).T
+    live = np.zeros((n_pages, PAGE), bool)
+    for s, n in enumerate(WALK_LENS):
+        pos = np.arange(n)
+        live[block[s, pos // PAGE], pos % PAGE] = True
+    live = jnp.asarray(live)[:, :, None, None]
+    pools = []
+    for k in (k1, k2):
+        x = jax.random.normal(k, (n_pages, PAGE, hkv, d), jnp.float32)
+        pools.append((jnp.where(live, x, jnp.nan).astype(dtype),
+                      jnp.where(live, x, 0.0).astype(dtype)))
+    q = jax.random.normal(k3, (b, h, d), jnp.float32).astype(dtype)
+    return q, pools, jnp.asarray(block), jnp.asarray(WALK_LENS, jnp.int32)
+
+
+@pytest.mark.parametrize("h,hkv,d", [
+    (15, 5, 64),     # smollm-360m, n_rep 3
+    (64, 8, 128),    # n_rep 8
+    (16, 16, 128),   # n_rep 1
+    (32, 32, 112),   # n_rep 1, D 112
+    (96, 8, 128),    # n_rep 12
+])
+def test_paged_decode_attention_walk_stops_at_length(h, hkv, d):
+    """The kernel on a pool that is NaN past every slot's length matches
+    the gathered jnp path on the zero-filled pool: it reads no row at or
+    past a length, and a slot of length 0 gives a zero row."""
+    from repro.kernels.decode_attention.ops import paged_decode_attention_op
+    from repro.kernels.decode_attention.ref import paged_decode_attention_ref
+
+    q, ((k_nan, k_zero), (v_nan, v_zero)), block, lens = _walk_pools(
+        h, hkv, d, jnp.float32)
+    out = paged_decode_attention_op(q, k_nan, v_nan, block, lens,
+                                    interpret=True)
+    ref = paged_decode_attention_ref(q, k_zero, v_zero, block, lens)
+    out = np.asarray(out)
+    assert np.isfinite(out).all()
+    np.testing.assert_array_equal(out[0], 0.0)
+    np.testing.assert_allclose(out[1:], np.asarray(ref)[1:],
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_paged_decode_attention_bf16_pool():
+    """bf16 pool and queries, as served: bf16 MXU operands with f32
+    accumulation against the jnp path's f32 arithmetic on the same values;
+    the bound is bf16 rounding of the softmax weights (2^-8 relative)."""
+    from repro.kernels.decode_attention.ops import paged_decode_attention_op
+    from repro.kernels.decode_attention.ref import paged_decode_attention_ref
+
+    q, ((k_nan, k_zero), (v_nan, v_zero)), block, lens = _walk_pools(
+        15, 5, 64, jnp.bfloat16)
+    out = paged_decode_attention_op(q, k_nan, v_nan, block, lens,
+                                    interpret=True)
+    ref = paged_decode_attention_ref(q, k_zero, v_zero, block, lens)
+    np.testing.assert_allclose(np.asarray(out, np.float32)[1:],
+                               np.asarray(ref, np.float32)[1:],
+                               rtol=2e-2, atol=2e-2)
 
 
 # ----------------------------------------------------------------- W4A16

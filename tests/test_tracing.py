@@ -209,6 +209,28 @@ def test_prefill_pad_counters(dense, chunk):
 
 
 @pytest.mark.parametrize("overlap", [False, True], ids=["sync", "overlap"])
+def test_decode_kv_page_counters(dense, overlap):
+    """Hand count, pages of 4, 8 pages a slot: prompts of 5 and 3 tokens,
+    4 new tokens each (the first from the prefill), so three decode steps
+    attend 6, 7, 8 and 4, 5, 6 positions: 2+2+2 and 1+2+2 live pages
+    against 2 slots x 8 pages a step.  The overlapped loop dispatches each
+    step one token ahead of its drain and must count the same."""
+    cfg, params = dense
+    eng = EngineCore(cfg, params, max_batch=2, max_seq=32, page_size=4,
+                     eos_id=-1, overlap=overlap)
+    for rid, n in enumerate((5, 3)):
+        eng.add_request(Request(rid=rid, prompt=list(range(2, 2 + n)),
+                                max_new_tokens=4))
+    while eng.has_work:
+        eng.step()
+    st = eng.stats
+    assert st.decode_steps == 3
+    assert st.decode_kv_live_pages == 6 + 5
+    assert st.decode_kv_table_pages == 3 * 2 * 8
+    assert "kv pages live=11/48 (22.9%)" in st.summary()
+
+
+@pytest.mark.parametrize("overlap", [False, True], ids=["sync", "overlap"])
 def test_first_token_hold(dense, overlap):
     cfg, params = dense
     eng = EngineCore(cfg, params, max_batch=2, max_seq=32, page_size=4,
